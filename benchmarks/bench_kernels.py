@@ -59,9 +59,9 @@ def ties_hist_fused_traffic(k: int, p: int, bins: int = 512) -> Dict:
     """Exact element counts for the fused histogram-TIES pipeline.
 
     Three grid passes over the flat batch (kernels/histogram.py):
-      amax:  read k*p (stack) + p (base); write k per block (negligible)
-      hist:  read k*p + p + amax meta;    write k*bins counts
-      merge: read k*p + p + thr meta;     write p merged elements
+      amax:  read k*p (stack) + p (base); write k per leaf (negligible)
+      hist:  read k*p + p + k amax;       write k*bins counts per leaf
+      merge: read k*p + p + k thresholds; write p merged elements
     Host-side threshold math touches only [k, bins] arrays.
     """
     elems = 3 * (k * p + p) + p + k * bins
@@ -179,16 +179,16 @@ def gates(quick: bool = True) -> List[Dict]:
     # oracle layout (see ref.ties_hist_ref docstring): threshold from
     # the unpadded row — eager, NOT jitted, since jit constant-folds
     # the cdf's /n into a reciprocal multiply and can shift a
-    # borderline bucket — then the merge on the block-padded layout
-    # the kernel sees (sub-SIMD tail widths reduce in a different
-    # order otherwise)
+    # borderline bucket — then the merge jitted, like the interpret-
+    # mode kernel body, on the block-padded layout the kernel sees
     block = kernel_env.block
     ident = True
+    jties = jax.jit(ref.ties_ref)
     for o, s, b, n in zip(outs, leaves, bases, lengths):
         thr = ref.hist_threshold_ref(s, b[None, :], 0.2, bins)
         sp, _ = pad_stacked(s, block)
         bp, _ = pad_flat(b, block)
-        r = ref.ties_ref(sp, bp[None, :], thr).reshape(-1)[:n]
+        r = jties(sp, bp[None, :], thr).reshape(-1)[:n]
         ident &= bool(np.array_equal(np.asarray(o), np.asarray(r)))
     out.append({"gate": "ties_hist_byte_identity", "ok": ident,
                 "value": float(ident), "threshold": 1.0,

@@ -20,7 +20,7 @@ Row = Tuple[str, float, str]
 
 
 def table3_tier1_raw(quick: bool = False) -> List[Row]:
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         tensors = controlled_tensors(9)
         t0 = time.perf_counter()
         res = audit_all_raw(tensors)
@@ -37,7 +37,7 @@ def table3_tier1_raw(quick: bool = False) -> List[Row]:
 
 
 def table4_tier1_wrapped(quick: bool = False) -> List[Row]:
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         tensors = controlled_tensors(9)
         t0 = time.perf_counter()
         res = audit_all_wrapped(tensors)

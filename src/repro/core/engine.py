@@ -771,7 +771,10 @@ def execute_plan(plan: MergePlan, contribs: Optional[Sequence[Any]], *,
     stacks plus concatenated copy are both transiently live, so the
     batch byte cap `max_batch_bytes` defaults to the largest single
     leaf's stack, keeping the batched peak within ~2 leaves' worth) at
-    a time — never the k full model copies the legacy path stacks.
+    a time — never the k full model copies the legacy path stacks. The
+    executor waits for each dispatch before it builds the next: on an
+    asynchronous device (a TPU) the host would otherwise enqueue many
+    dispatches ahead, and all their transients would be live at once.
 
     `pallas=True` routes linear-family batches through the fused
     `kernels/nary_accum` Pallas kernel (fp32 accumulation; validated to
@@ -849,8 +852,8 @@ def execute_plan(plan: MergePlan, contribs: Optional[Sequence[Any]], *,
                 cache.note_stacked(t.stacked_nbytes)
                 kw = dict(strat.defaults)
                 kw.update(cfg)
-                val, acc = run_fold(strat.fold, new, b, acc=aux, k=t.k,
-                                    **kw)
+                val, acc = jax.block_until_ready(
+                    run_fold(strat.fold, new, b, acc=aux, k=t.k, **kw))
                 outputs[t.index] = val
                 cache.stats["leaf_tasks"] += 1
                 cache.stats["dispatches"] += 1
@@ -878,6 +881,7 @@ def execute_plan(plan: MergePlan, contribs: Optional[Sequence[Any]], *,
                             strat, plan, group, leaf_of, base_leaves,
                             cache, pallas=pallas, leaf_raw=leaf_raw)
                         cache.stats["batched_leaves"] += len(group)
+                    jax.block_until_ready(out)
                     cache.stats["dispatches"] += 1
                     cache.stats["leaf_tasks"] += len(group)
                     for t, o, a in zip(group, out, auxs):

@@ -16,8 +16,7 @@ from typing import Tuple
 
 import jax
 import jax.numpy as jnp
-
-DEFAULT_BLOCK = 2048
+from jax.experimental import pallas as pl
 
 
 def pad_flat(x: jax.Array, block: int) -> Tuple[jax.Array, int]:
@@ -59,6 +58,14 @@ def pad_stacked_raw(s: jax.Array, block: int) -> Tuple[jax.Array, int]:
     return flat, n
 
 
+def leaf_row_spec(k: int, last: int) -> pl.BlockSpec:
+    """The grid step's row of a per-leaf [L, k, last] table in a flat
+    batch, picked through the block -> leaf map that is the first
+    scalar-prefetch operand. Consecutive blocks of one leaf map to one
+    row, so it is fetched (or written back) once per leaf."""
+    return pl.BlockSpec((None, k, last), lambda i, leaf, *_: (leaf[i], 0, 0))
+
+
 def hash_uniform(idx: jax.Array, seed) -> jax.Array:
     """Deterministic uniform(0,1) floats from uint32 element indices.
 
@@ -71,15 +78,7 @@ def hash_uniform(idx: jax.Array, seed) -> jax.Array:
     h = h ^ (h >> 13)
     h = h * jnp.uint32(0xC2B2AE35)
     h = h ^ (h >> 16)
-    return (h >> 8).astype(jnp.float32) * jnp.float32(1.0 / (1 << 24))
-
-
-def default_interpret() -> bool:
-    """Effective interpret flag, delegated to the central `KernelEnv`.
-
-    Kept as a thin shim for callers that predate `kernels.config`; the
-    backend probe runs at most once per process (cached on the env) and
-    `REPRO_KERNEL_INTERPRET` overrides it.
-    """
-    from repro.kernels.config import kernel_env
-    return kernel_env.resolve_interpret()
+    # through int32: Mosaic has no uint32 -> float32 cast, and the value
+    # is below 2^24, so both casts are exact
+    return (h >> 8).astype(jnp.int32).astype(jnp.float32) * \
+        jnp.float32(1.0 / (1 << 24))

@@ -78,7 +78,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
                               "scale"))
 def flash_attention(q, k, v, *, causal: bool = True, scale: float = 0.0,
                     block_q: int = 128, block_k: int = 128,
-                    interpret: bool = True):
+                    interpret: bool):
     """q: [B, Sq, H, D]; k/v: [B, Sk, HK, D] (H a multiple of HK).
 
     Returns [B, Sq, H, D]. Sq/Sk padded internally to block multiples.

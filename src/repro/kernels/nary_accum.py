@@ -32,8 +32,8 @@ def _nary_kernel(x_ref, base_ref, w_ref, out_ref):
 
 
 @functools.partial(jax.jit, static_argnames=("block", "interpret"))
-def nary_accum_pallas(stacked, base, weights, *, block: int = 2048,
-                      interpret: bool = True):
+def nary_accum_pallas(stacked, base, weights, *, block: int,
+                      interpret: bool):
     """stacked: [k, Np]; base: [1, Np]; weights: [k, 1] fp32."""
     k, npad = stacked.shape
     grid = (npad // block,)

@@ -23,16 +23,12 @@ import jax.numpy as jnp
 
 from repro.kernels.common import pad_flat, pad_stacked, pad_stacked_raw
 from repro.kernels.config import kernel_env
-from repro.kernels.dare import dare_block_pallas, dare_pallas, leaf_meta
+from repro.kernels.dare import dare_block_pallas, dare_pallas, seed_bits
 from repro.kernels.histogram import batch_layout, ties_hist_batch
 from repro.kernels.nary_accum import nary_accum_pallas
 from repro.kernels.quant import quant_nary_pallas
 from repro.kernels.slerp import slerp_pallas
 from repro.kernels.ties import ties_pallas
-
-# Backwards-compatible re-export: pre-KernelEnv callers imported the
-# block constant from here via kernels.common.
-DEFAULT_BLOCK = 2048
 
 
 def _defaults(block: Optional[int],
@@ -74,8 +70,9 @@ def _flat_batch(leaves: Sequence[jax.Array], base_leaves: Sequence[jax.Array],
     """Pad each leaf to a block multiple and concatenate.
 
     `leaves[j]`: [k, n_j] (same k); `base_leaves[j]`: [n_j]. Returns
-    (stacked [k, Np], base [1, Np], lengths, leaf_id, valid, offsets)
-    where `offsets[j]` is leaf j's padded start column.
+    (stacked [k, Np], base [1, Np], lengths, leaf_id, first, offsets)
+    where `leaf_id`/`first` are `histogram.batch_layout`'s block -> leaf
+    map and `offsets[j]` is leaf j's padded start column.
     """
     pad_s = pad_stacked_raw if raw else pad_stacked
     parts, bparts, lengths, offsets = [], [], [], []
@@ -90,9 +87,9 @@ def _flat_batch(leaves: Sequence[jax.Array], base_leaves: Sequence[jax.Array],
         off += sp.shape[1]
     stacked = jnp.concatenate(parts, axis=1)
     base = jnp.concatenate(bparts)[None, :]
-    leaf_id, valid, total = batch_layout(lengths, block)
+    leaf_id, first, total = batch_layout(lengths, block)
     assert total == stacked.shape[1]
-    return stacked, base, lengths, leaf_id, valid, offsets
+    return stacked, base, lengths, leaf_id, first, offsets
 
 
 def _split_flat(out, lengths: List[int], offsets: List[int],
@@ -114,10 +111,10 @@ def ties_batch_merge(leaves: Sequence[jax.Array],
     """
     block, interpret = _defaults(block, interpret)
     bins = kernel_env.hist_bins if bins is None else bins
-    stacked, base, lengths, leaf_id, valid, offsets = _flat_batch(
+    stacked, base, lengths, leaf_id, first, offsets = _flat_batch(
         leaves, base_leaves, block)
     out = ties_hist_batch(
-        stacked, base, leaf_id, valid,
+        stacked, base, leaf_id, first,
         jnp.asarray(lengths, jnp.int32),
         trim=trim, bins=bins, block=block, interpret=interpret)
     return _split_flat(out, lengths, offsets, block)
@@ -135,13 +132,15 @@ def dare_batch_merge(leaves: Sequence[jax.Array],
     plan's global leaf index into it so replicas agree).
     """
     block, interpret = _defaults(block, interpret)
-    stacked, base, lengths, leaf_id, valid, offsets = _flat_batch(
+    stacked, base, lengths, leaf_id, first, offsets = _flat_batch(
         leaves, base_leaves, block)
-    metas = [leaf_meta(jnp.uint32(s), -(-ln // block) * block, block)
-             for s, ln in zip(seeds, lengths)]
-    meta = jnp.concatenate(metas, axis=0)
-    out = dare_block_pallas(stacked, base, meta, p=p, block=block,
-                            interpret=interpret)
+    # per leaf: (seed bits, padded length, first block)
+    meta = jnp.concatenate([
+        jnp.concatenate([seed_bits(s), jnp.asarray(
+            [-(-ln // block) * block, off // block], jnp.int32)])
+        for s, ln, off in zip(seeds, lengths, offsets)])
+    out = dare_block_pallas(stacked, base, leaf_id, meta, p=p,
+                            block=block, interpret=interpret)
     return _split_flat(out, lengths, offsets, block)
 
 
@@ -159,13 +158,13 @@ def quant_batch_merge(q_leaves: Sequence[jax.Array],
     `ref.quant_nary_ref`.
     """
     block, interpret = _defaults(block, interpret)
-    stacked, base, lengths, leaf_id, valid, offsets = _flat_batch(
+    stacked, base, lengths, leaf_id, first, offsets = _flat_batch(
         q_leaves, base_leaves, block, raw=True)
-    scale_rows = jnp.stack([jnp.asarray(s, jnp.float32) for s in scales])
-    scale_meta = scale_rows[leaf_id]                       # [nb, k]
+    scale_rows = jnp.stack([jnp.asarray(s, jnp.float32).reshape(-1, 1)
+                            for s in scales])              # [L, k, 1]
     w = jnp.asarray(weights, jnp.float32).reshape(-1, 1)
-    out = quant_nary_pallas(stacked, base, scale_meta, w, block=block,
-                            interpret=interpret)
+    out = quant_nary_pallas(stacked, base, leaf_id, scale_rows, w,
+                            block=block, interpret=interpret)
     return _split_flat(out, lengths, offsets, block)
 
 

@@ -61,11 +61,13 @@ def ties_hist_ref(stacked, base, trim=0.2, bins=512):
     """Per-leaf eager oracle for histogram-trim TIES:
     `hist_threshold_ref` then `ties_ref`.
 
-    Byte-identity caveat: XLA CPU's axis-0 reduction order can shift by
-    an ulp at sub-SIMD tail widths (observed at k=16, n=7), so bitwise
-    comparisons against the kernel should evaluate the MERGE half on
-    the same block-padded layout the kernel sees — thresholds from the
-    unpadded row (exact either way), `ties_ref` on the padded stack."""
+    Byte-identity caveat: XLA CPU's axis-0 reduction inside a jitted
+    computation (which is how the interpret-mode kernel body runs) can
+    differ by an ulp from the op-by-op one (observed at k=16), so
+    bitwise comparisons against the kernel should evaluate the MERGE
+    half jitted, on the same block-padded layout the kernel sees —
+    thresholds eagerly from the unpadded row (exact either way),
+    `jax.jit(ties_ref)` on the padded stack."""
     return ties_ref(stacked, base,
                     hist_threshold_ref(stacked, base, trim, bins))
 

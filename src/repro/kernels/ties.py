@@ -41,8 +41,8 @@ def _ties_kernel(x_ref, base_ref, thr_ref, out_ref):
 
 @functools.partial(jax.jit,
                    static_argnames=("block", "interpret"))
-def ties_pallas(stacked, base, thresholds, *, block: int = 2048,
-                interpret: bool = True):
+def ties_pallas(stacked, base, thresholds, *, block: int,
+                interpret: bool):
     """stacked: [k, Np] fp32 (padded); base: [1, Np]; thresholds: [k, 1]."""
     k, npad = stacked.shape
     grid = (npad // block,)

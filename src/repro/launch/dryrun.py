@@ -1,6 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell.
 
 For each cell we jit the appropriate step function (train_step for train
@@ -15,24 +12,29 @@ shardings over the production mesh, lower against ShapeDtypeStruct inputs
 
 Artifacts are written to experiments/dryrun/<cell>.json and consumed by
 benchmarks/roofline.py.
+
+`main()` gives the host platform 512 devices (`XLA_FLAGS`) before JAX
+starts its backend; importing this module changes nothing.
 """
-import argparse  # noqa: E402
-import json  # noqa: E402
-import re  # noqa: E402
-import time  # noqa: E402
-import traceback  # noqa: E402
-from typing import Dict, Optional  # noqa: E402
+import argparse
+import json
+import os
+import re
+import time
+import traceback
+from typing import Dict, Optional
 
-import jax  # noqa: E402
-import jax.numpy as jnp  # noqa: E402
-from jax.sharding import NamedSharding, PartitionSpec as P  # noqa: E402
+import jax
+import jax.numpy as jnp
+from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.configs import SHAPES, get_config, list_archs  # noqa: E402
-from repro.data.synthetic import batch_shapes  # noqa: E402
-from repro.launch.mesh import make_production_mesh  # noqa: E402
-from repro.models.model import Model  # noqa: E402
-from repro.sharding import policy  # noqa: E402
-from repro.train.step import make_train_step, train_state_shapes  # noqa: E402
+from repro.configs import SHAPES, get_config, list_archs
+from repro.data.synthetic import batch_shapes
+from repro.launch.compile_cache import place_compile_cache
+from repro.launch.mesh import make_production_mesh
+from repro.models.model import Model
+from repro.sharding import policy
+from repro.train.step import make_train_step, train_state_shapes
 
 DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "bf16": 2, "f16": 2,
@@ -179,8 +181,6 @@ def dryrun_cell(arch: str, shape_name: str, *, multi_pod: bool = False,
         t_compile = time.time() - t0 - t_lower
 
         ca = compiled.cost_analysis() or {}
-        if isinstance(ca, (list, tuple)):   # jax<=0.4.x: list of per-device
-            ca = ca[0] if ca else {}        # dicts; 0.5+: a single dict
         ma = compiled.memory_analysis()
         hlo = compiled.as_text()
         # trip-count-aware cost model (XLA's cost_analysis counts while
@@ -322,6 +322,8 @@ def _model_flops(cfg, shape) -> Dict:
 
 
 def main() -> None:
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+    place_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="all")
     ap.add_argument("--shape", default="all")

@@ -25,6 +25,7 @@ from repro.api import MergeSpec, Replica
 from repro.checkpoint import restore_checkpoint, save_checkpoint
 from repro.configs import get_config, smoke_config
 from repro.core.resolve import seed_from_root
+from repro.launch.compile_cache import place_compile_cache
 from repro.models.model import Model
 from repro.obs import EventLog
 from repro.train.step import init_train_state
@@ -52,6 +53,7 @@ def main() -> None:
     ap.add_argument("--events-out", default="",
                     help="also write the event stream to this JSONL file")
     args = ap.parse_args()
+    place_compile_cache()
 
     cfg = smoke_config(args.arch) if args.smoke else get_config(args.arch)
     model = Model(cfg)

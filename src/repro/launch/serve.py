@@ -15,6 +15,7 @@ import numpy as np
 from repro.configs import get_config, smoke_config
 from repro.configs.base import ShapeSpec
 from repro.data.synthetic import make_batch
+from repro.launch.compile_cache import place_compile_cache
 from repro.models.model import Model
 from repro.train.serve import greedy_decode
 
@@ -27,6 +28,7 @@ def main() -> None:
     ap.add_argument("--prompt-len", type=int, default=16)
     ap.add_argument("--gen", type=int, default=8)
     args = ap.parse_args()
+    place_compile_cache()
 
     cfg = smoke_config(args.arch) if args.smoke else get_config(args.arch)
     model = Model(cfg)

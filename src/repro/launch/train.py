@@ -22,6 +22,7 @@ from repro.checkpoint import latest_checkpoint, restore_checkpoint, \
     save_checkpoint
 from repro.configs import get_config, smoke_config
 from repro.data.synthetic import SyntheticTask
+from repro.launch.compile_cache import place_compile_cache
 from repro.launch.mesh import make_mesh
 from repro.models.model import Model
 from repro.sharding import policy
@@ -45,6 +46,7 @@ def main() -> None:
     ap.add_argument("--task", type=int, default=0,
                     help="synthetic task id (branch divergence for merging)")
     args = ap.parse_args()
+    place_compile_cache()
 
     cfg = smoke_config(args.arch) if args.smoke else get_config(args.arch)
     cfg = cfg.replace(grad_accum=max(1, min(cfg.grad_accum, args.batch)))

@@ -190,7 +190,10 @@ def list_strategies() -> List[str]:
 
 
 def leafwise(leaf_fn: Callable, needs_key: bool = False) -> Callable:
-    """Lift a per-leaf function (stacked [k,...], base, [key]) -> leaf."""
+    """Lift a per-leaf function (stacked [k,...], base, [key]) -> leaf.
+
+    Each leaf is waited for before the next is enqueued, so on an
+    asynchronous device one leaf's transients are live at a time."""
     def nary(stacked, base, seed, **cfg):
         leaves_s, treedef = jax.tree_util.tree_flatten(stacked)
         leaves_b = treedef.flatten_up_to(base)
@@ -202,5 +205,6 @@ def leafwise(leaf_fn: Callable, needs_key: bool = False) -> Callable:
                 outs.append(leaf_fn(sl, bl, key, **cfg))
             else:
                 outs.append(leaf_fn(sl, bl, **cfg))
+            jax.block_until_ready(outs[-1])
         return jax.tree_util.tree_unflatten(treedef, outs)
     return nary

@@ -15,7 +15,7 @@ def rng():
 @pytest.fixture(scope="session")
 def tensors4x4():
     from repro.core.properties import controlled_tensors
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         yield controlled_tensors(9, dtype=jnp.float64)
 
 

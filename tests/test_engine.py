@@ -48,7 +48,7 @@ def _pytree_contribs(k=3, seed=0):
 
 @pytest.fixture(scope="module")
 def x64():
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         yield
 
 

@@ -14,7 +14,7 @@ from repro.strategies import get_strategy, list_strategies
 
 @pytest.fixture(scope="module")
 def x64():
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         yield
 
 
