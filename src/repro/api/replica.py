@@ -37,10 +37,10 @@ from typing import Any, Dict, Iterable, Optional
 
 from repro.api.spec import MergeSpec
 from repro.core.engine import CacheInfo, EngineCache
-from repro.core.hashing import pytree_digest
+from repro.core.hashing import pytree_digest, tree_nbytes
 from repro.core.state import CRDTMergeState
 from repro.core.trust import TrustState
-from repro.obs import MetricsRegistry
+from repro.obs import MetricsRegistry, span
 
 __all__ = ["Replica"]
 
@@ -113,15 +113,28 @@ class Replica:
         (Remark-16 reference semantics — the choice is part of every
         cache key). Pass the pytree's own paths (`leaf_paths_of`) or
         let validation catch a mismatch."""
-        eid = element_id or pytree_digest(contribution).hex()
-        if self._node is not None:
-            self._node.contribute(contribution, element_id=eid,
-                                  leaves=leaves)
-        else:
-            self._set_state(self._state.add(contribution, self.node_id,
-                                            element_id=eid,
-                                            leaf_paths=leaves))
+        with span("layer1.contribute") as sp:
+            eid = element_id or self._content_id(contribution, "contribute")
+            sp.set(eid=eid[:16])
+            if self._node is not None:
+                self._node.contribute(contribution, element_id=eid,
+                                      leaves=leaves)
+            else:
+                self._set_state(self._state.add(contribution, self.node_id,
+                                                element_id=eid,
+                                                leaf_paths=leaves))
         return eid
+
+    def _content_id(self, tree: Any, site: str) -> str:
+        """Content id of `tree` (hex SHA-256), spanned as
+        `layer1.digest` and counted under `hash_bytes_total{site}` on
+        this replica's registry."""
+        nbytes = tree_nbytes(tree)
+        with span("layer1.digest", bytes=nbytes) as sp:
+            ref = pytree_digest(tree).hex()
+            sp.set(eid=ref[:16])
+        self.obs.counter("hash_bytes_total").inc(nbytes, site=site)
+        return ref
 
     def add(self, contribution: Any, *,
             leaves: Optional[Iterable[str]] = None,
@@ -132,10 +145,12 @@ class Replica:
 
     def retract(self, element_id: str) -> None:
         """OR-Set remove: tombstone every observed tag of the element."""
-        if self._node is not None:
-            self._node.retract(element_id)
-        else:
-            self._set_state(self._state.remove(element_id, self.node_id))
+        with span("layer1.retract", eid=element_id[:16]):
+            if self._node is not None:
+                self._node.retract(element_id)
+            else:
+                self._set_state(self._state.remove(element_id,
+                                                   self.node_id))
 
     def merge(self, other: Any) -> "Replica":
         """CRDT join with another Replica, a raw CRDTMergeState, or an
@@ -180,7 +195,7 @@ class Replica:
         """Pin a base model; returns its content ref for
         `MergeSpec(base_ref=...)`. Content-addressed: the ref fully
         determines the bytes, so specs carrying it are portable."""
-        ref = pytree_digest(payload).hex()
+        ref = self._content_id(payload, "register_base")
         self._bases[ref] = payload
         return ref
 

@@ -99,7 +99,7 @@ import jax.numpy as jnp
 from repro.api.spec import coerce_spec, MergeSpec
 from repro.core.compression import (
     compressed_tree_to_structure, CompressedLeaf, CompressedTree)
-from repro.core.hashing import pytree_digest, tensor_digest
+from repro.core.hashing import pytree_digest, tensor_digest, tree_nbytes
 from repro.obs import CounterView, MetricsRegistry, span
 from repro.strategies import get_strategy
 from repro.strategies.base import run_fold, Strategy
@@ -189,8 +189,8 @@ _META_MEMO: "OrderedDict[str, ContribMeta]" = OrderedDict()
 _META_MEMO_LIMIT = 1024
 
 
-def contrib_meta(contribution: Any, *, eid: Optional[str] = None
-                 ) -> ContribMeta:
+def contrib_meta(contribution: Any, *, eid: Optional[str] = None,
+                 obs: Optional[MetricsRegistry] = None) -> ContribMeta:
     """Flatten + digest one contribution; memoized by content id.
 
     Quantized contributions (`CompressedTree`) are planned in place:
@@ -198,7 +198,11 @@ def contrib_meta(contribution: Any, *, eid: Optional[str] = None
     on a transient per-leaf dequantization (one leaf live at a time —
     never the k x P densified copy), and the per-leaf scales ride into
     the meta so the plan can account int8 wire bytes and the executor
-    can route the batch through the merge-on-arrival kernel."""
+    can route the batch through the merge-on-arrival kernel.
+
+    A miss (not a memo hit) is spanned as `engine.contrib_meta` and
+    its hashed bytes counted under `hash_bytes_total{site=contrib_meta}`
+    on `obs`, the registry of the replica that asked."""
     if eid is not None and eid in _META_MEMO:
         _META_MEMO.move_to_end(eid)
         return _META_MEMO[eid]
@@ -208,10 +212,19 @@ def contrib_meta(contribution: Any, *, eid: Optional[str] = None
         contribution, is_leaf=_is_qleaf)
     leaves = [l for _, l in flat]
     quantized = any(_is_qleaf(l) for l in leaves)
+    digests: List[bytes] = []
+    nbytes = 0
+    with span("engine.contrib_meta", eid=eid[:16] if eid else None) as sp:
+        for l in leaves:
+            dense = _dense_leaf(l, obs=None)
+            digests.append(tensor_digest(dense))
+            nbytes += tree_nbytes(dense)
+        sp.set(bytes=nbytes)
+    if obs is not None:
+        obs.counter("hash_bytes_total").inc(nbytes, site="contrib_meta")
     meta = ContribMeta(
         treedef=treedef,
-        digests=tuple(tensor_digest(_dense_leaf(l, obs=None))
-                      for l in leaves),
+        digests=tuple(digests),
         shapes=tuple(tuple(l.shape) if _is_qleaf(l) else tuple(jnp.shape(l))
                      for l in leaves),
         dtypes=tuple(jnp.dtype(l.dtype) if _is_qleaf(l)
@@ -358,11 +371,17 @@ def plan_merge(metas: Sequence[ContribMeta],
                reduction: Optional[str] = None,
                spec: Optional[MergeSpec] = None,
                coverages: Optional[Sequence[Optional[Tuple[str, ...]]]]
-               = None, **cfg) -> MergePlan:
+               = None, obs: Optional[MetricsRegistry] = None,
+               **cfg) -> MergePlan:
     """Emit a per-leaf merge plan from contribution metadata (canonical
     order). Payloads are not needed to plan — only their digests. Takes
     either a MergeSpec (`spec=`) or the legacy strategy-name + kwargs
     form (wrapped in a lenient spec).
+
+    With a base, its leaves are digested here (span
+    `engine.base_digest`), and the bytes counted under
+    `hash_bytes_total{site=base}` on `obs`, the registry of the replica
+    that asked.
 
     `coverages` (parallel to `metas`) marks sparse contributions: a
     tuple of keystr leaf paths the contribution carries, or None for
@@ -447,7 +466,11 @@ def plan_merge(metas: Sequence[ContribMeta],
             base_frags: Sequence[bytes] = [_NO_BASE] * n_leaves
         else:
             base_leaves = treedef.flatten_up_to(base)
-            base_frags = [tensor_digest(bl) for bl in base_leaves]
+            nbytes = tree_nbytes(base_leaves)
+            with span("engine.base_digest", bytes=nbytes):
+                base_frags = [tensor_digest(bl) for bl in base_leaves]
+            if obs is not None:
+                obs.counter("hash_bytes_total").inc(nbytes, site="base")
         tasks: List[LeafTask] = []
         base_only: List[int] = []
         for i in range(n_leaves):
@@ -498,13 +521,14 @@ def plan_for(contribs: Sequence[Any],
              reduction: Optional[str] = None,
              spec: Optional[MergeSpec] = None,
              coverages: Optional[Sequence[Optional[Tuple[str, ...]]]]
-             = None, **cfg) -> MergePlan:
+             = None, obs: Optional[MetricsRegistry] = None,
+             **cfg) -> MergePlan:
     """Convenience planner over resident payloads (ids memoize digests)."""
     ids: Sequence[Optional[str]] = contrib_ids or [None] * len(contribs)
-    metas = [contrib_meta(c, eid=e) for c, e in zip(contribs, ids)]
+    metas = [contrib_meta(c, eid=e, obs=obs) for c, e in zip(contribs, ids)]
     return plan_merge(metas, strategy_name, base=base, seed=seed,
                       reduction=reduction, spec=spec,
-                      coverages=coverages, **cfg)
+                      coverages=coverages, obs=obs, **cfg)
 
 
 def _leaf_paths(treedef) -> List[str]:
@@ -852,8 +876,9 @@ def execute_plan(plan: MergePlan, contribs: Optional[Sequence[Any]], *,
                 cache.note_stacked(t.stacked_nbytes)
                 kw = dict(strat.defaults)
                 kw.update(cfg)
-                val, acc = jax.block_until_ready(
-                    run_fold(strat.fold, new, b, acc=aux, k=t.k, **kw))
+                folded = run_fold(strat.fold, new, b, acc=aux, k=t.k, **kw)
+                with span("engine.wait", leaves=1):
+                    val, acc = jax.block_until_ready(folded)
                 outputs[t.index] = val
                 cache.stats["leaf_tasks"] += 1
                 cache.stats["dispatches"] += 1
@@ -881,7 +906,8 @@ def execute_plan(plan: MergePlan, contribs: Optional[Sequence[Any]], *,
                             strat, plan, group, leaf_of, base_leaves,
                             cache, pallas=pallas, leaf_raw=leaf_raw)
                         cache.stats["batched_leaves"] += len(group)
-                    jax.block_until_ready(out)
+                    with span("engine.wait", leaves=len(group)):
+                        jax.block_until_ready(out)
                     cache.stats["dispatches"] += 1
                     cache.stats["leaf_tasks"] += len(group)
                     for t, o, a in zip(group, out, auxs):
@@ -1369,7 +1395,7 @@ def merge(contribs: Sequence[Any], strategy_name: Optional[str] = None, *,
     cache.stats["planned_merges"] += 1
     plan = plan_for(contribs, contrib_ids=contrib_ids,
                     base=base, seed=seed, spec=spec,
-                    coverages=coverages)
+                    coverages=coverages, obs=cache.obs)
     return execute_plan(plan, contribs, base=base, use_cache=use_cache,
                         max_batch_bytes=max_batch_bytes, pallas=pallas,
                         cache=cache)

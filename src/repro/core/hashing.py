@@ -10,6 +10,11 @@ Two tiers:
     sharding combine with an integer psum to the identical value. Used as
     the intra-cluster fast path for dedup; SHA-256 remains the canonical
     identity.
+
+`tensor_digest` works on the host in two steps, spanned apart:
+`hash.fetch` (the copy of the array to host memory) and `hash.sha256`
+(the contiguous copy and the SHA-256). `tree_nbytes` gives the bytes a
+digest of a pytree hashes, for its caller's `hash_bytes_total`.
 """
 from __future__ import annotations
 
@@ -20,6 +25,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.obs import span
+
 _MIX_A = np.uint32(2654435761)   # Knuth multiplicative
 _MIX_B = np.uint32(0x9E3779B9)
 _MIX_C = np.uint32(0x85EBCA6B)
@@ -27,14 +34,23 @@ _MIX_D = np.uint32(0xC2B2AE35)
 
 
 def tensor_digest(arr) -> bytes:
-    a = np.asarray(arr)
-    h = hashlib.sha256()
-    h.update(str(a.dtype).encode())
-    h.update(b"|")
-    h.update(str(a.shape).encode())
-    h.update(b"|")
-    h.update(np.ascontiguousarray(a).tobytes())
-    return h.digest()
+    with span("hash.fetch"):
+        a = np.asarray(arr)
+    with span("hash.sha256"):
+        h = hashlib.sha256()
+        h.update(str(a.dtype).encode())
+        h.update(b"|")
+        h.update(str(a.shape).encode())
+        h.update(b"|")
+        h.update(np.ascontiguousarray(a).tobytes())
+        return h.digest()
+
+
+def tree_nbytes(tree) -> int:
+    """Bytes of a pytree's leaves: what `pytree_digest` hashes (read
+    from the arrays' metadata; nothing is copied to the host)."""
+    return sum(int(getattr(l, "nbytes", None) or np.asarray(l).nbytes)
+               for l in jax.tree_util.tree_leaves(tree))
 
 
 def pytree_digest(tree) -> bytes:

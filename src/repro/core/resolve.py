@@ -145,12 +145,14 @@ def _merge_ids(store: Dict[str, Any], ids: List[str], spec: MergeSpec,
                            spec=spec, cache=cache, coverages=covs)
         return out, store
 
-    # engine route: plan from resident payloads + memoized digests
+    # engine route: plan from resident payloads + memoized digests; the
+    # hashes count on the registry of the cache's owner
+    obs = (cache if cache is not None else default_cache()).obs
     metas = {}
     unknown = []
     for i in ids:
         if i in store:
-            metas[i] = engine.contrib_meta(store[i], eid=i)
+            metas[i] = engine.contrib_meta(store[i], eid=i, obs=obs)
         else:
             m = engine.memoized_meta(i)
             if m is None:
@@ -168,9 +170,9 @@ def _merge_ids(store: Dict[str, Any], ids: List[str], spec: MergeSpec,
             [i for i in ids if i not in store]
         store = _fetch_into(store, need, fetch)
         for i in unknown:
-            metas[i] = engine.contrib_meta(store[i], eid=i)
+            metas[i] = engine.contrib_meta(store[i], eid=i, obs=obs)
     plan = engine.plan_merge([metas[i] for i in ids], base=base,
-                             seed=seed, spec=spec, coverages=covs)
+                             seed=seed, spec=spec, coverages=covs, obs=obs)
     absent = [i for i in ids if i not in store]
     if absent:
         if use_cache:

@@ -41,6 +41,7 @@ from typing import Any, Dict, FrozenSet, Iterable, Mapping, Optional, Tuple
 from repro.core.hashing import leaf_paths_of, pytree_digest
 from repro.core.merkle import merkle_root
 from repro.core.version_vector import VersionVector
+from repro.obs import span
 
 
 @dataclass(frozen=True, order=True)
@@ -237,11 +238,12 @@ class CRDTMergeState:
         and their tombstones once observed by all replicas. Must only be
         invoked after resolve() output dissemination."""
         stable = set(stable_tags) & self.removes
-        adds = frozenset(e for e in self.adds if e.tag not in stable)
-        removes = self.removes - stable
-        live = {e.element_id for e in adds}
-        store = {k: v for k, v in self.store.items() if k in live}
-        return CRDTMergeState(adds, removes, self.vv, store)
+        with span("layer1.gc", n=len(stable)):
+            adds = frozenset(e for e in self.adds if e.tag not in stable)
+            removes = self.removes - stable
+            live = {e.element_id for e in adds}
+            store = {k: v for k, v in self.store.items() if k in live}
+            return CRDTMergeState(adds, removes, self.vv, store)
 
     def __repr__(self) -> str:
         return (f"CRDTMergeState(|A|={len(self.adds)}, |R|={len(self.removes)}"

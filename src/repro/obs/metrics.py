@@ -128,6 +128,11 @@ declare("engine_quant_leaves_merged_total", "counter",
 declare("resolve_fold_updates_total", "counter",
         "Contributions folded into cached accumulators by prefix-fold "
         "resumption (per EngineCache)", deterministic=True)
+declare("hash_bytes_total", "counter",
+        "Bytes hashed by host SHA-256, by call site: the content id in "
+        "contribute, the planner's leaf digests in contrib_meta, the "
+        "base-leaf digests of a plan, the base ref in register_base",
+        labels=("site",))
 declare("resolve_layer1_overhead_ms", "histogram",
         "CRDT-side resolve overhead: gate + canonical order + Merkle "
         "root + seed derivation, per resolve (the paper's <0.5 ms claim)",

@@ -15,6 +15,14 @@ Span identity is also deterministic: ids are sequential per tracer
 (`s1`, `s2`, …), never random, so two runs of one simulated schedule
 diff clean.
 
+A tracer may also mirror its spans onto another clock: `annotate` is a
+callable that takes a span's name and returns a context manager, which
+the tracer enters when the span opens and leaves when it closes. Pass
+`jax.profiler.TraceAnnotation` to put the spans on the profiler's
+trace beside the device's operations, so that an idle gap of the
+device can be named by what the host was doing in it. This module
+imports no JAX; the caller hands the hook in.
+
 There is one process-default tracer slot (`set_tracer` /
 `current_tracer`). The module-level `span()` helper is the zero-cost
 path: when no tracer is installed — or observability is disabled via
@@ -77,13 +85,15 @@ class Span:
 
 
 class _ActiveSpan:
-    """Context-manager handle pairing a Span with its tracer."""
+    """Context-manager handle pairing a Span with its tracer (and the
+    tracer's annotation of it, if the tracer has an `annotate` hook)."""
 
-    __slots__ = ("_tracer", "span")
+    __slots__ = ("_tracer", "span", "_annotation")
 
-    def __init__(self, tracer: "Tracer", span: Span):
+    def __init__(self, tracer: "Tracer", span: Span, annotation: Any = None):
         self._tracer = tracer
         self.span = span
+        self._annotation = annotation
 
     def set(self, **attrs: Any) -> "_ActiveSpan":
         self.span.attrs.update(attrs)
@@ -95,6 +105,8 @@ class _ActiveSpan:
     def __exit__(self, exc_type, exc, tb) -> None:
         if exc_type is not None:
             self.span.attrs.setdefault("error", exc_type.__name__)
+        if self._annotation is not None:
+            self._annotation.__exit__(exc_type, exc, tb)
         self._tracer._finish(self.span)
 
 
@@ -102,13 +114,19 @@ class Tracer:
     """Collects completed spans in end order (a child always precedes
     its parent, as in the module example). `clock` is any zero-arg
     callable returning a float; bind it to the simulator's virtual
-    clock for deterministic traces."""
+    clock for deterministic traces. `annotate`, if given, is called
+    with each span's name and must return a context manager that
+    stays entered while the span is open (see the module docstring).
+    """
 
-    __slots__ = ("clock", "spans", "_stack", "_next_id", "meta")
+    __slots__ = ("clock", "spans", "_stack", "_next_id", "meta",
+                 "annotate")
 
     def __init__(self, clock: Callable[[], float] = time.monotonic,
+                 annotate: Optional[Callable[[str], Any]] = None,
                  **meta: Any):
         self.clock = clock
+        self.annotate = annotate
         self.spans: List[Span] = []
         self._stack: List[Span] = []
         self._next_id = 0
@@ -119,7 +137,11 @@ class Tracer:
         parent = self._stack[-1].span_id if self._stack else None
         sp = Span(f"s{self._next_id}", parent, name, self.clock(), attrs)
         self._stack.append(sp)
-        return _ActiveSpan(self, sp)
+        annotation = None
+        if self.annotate is not None:
+            annotation = self.annotate(name)
+            annotation.__enter__()
+        return _ActiveSpan(self, sp, annotation)
 
     def _finish(self, sp: Span) -> None:
         sp.t1 = self.clock()

@@ -3,6 +3,7 @@ and the PR's inertness contract: tracing on never changes a merged
 byte, and identical converged contribution sets produce identical
 deterministic aggregates regardless of delivery order (20 orderings).
 """
+import contextlib
 import io
 import json
 
@@ -264,14 +265,29 @@ def _contribs(k, shape=(8, 8), seed=3):
             for _ in range(k)]
 
 
-def test_all_26_strategies_byte_identical_with_tracing_on():
-    """Enabling spans + the Layer-1 timer must not move a single output
-    byte, across the full strategy catalog."""
+class _RecordingAnnotate:
+    """A fake `Tracer(annotate=...)` hook: counts what it annotates."""
+
+    def __init__(self):
+        self.entered = 0
+
+    def __call__(self, name):
+        self.entered += 1
+        return contextlib.nullcontext()
+
+
+@pytest.mark.parametrize("annotate", [None, _RecordingAnnotate],
+                         ids=["no-hook", "recording-hook"])
+def test_all_26_strategies_byte_identical_with_tracing_on(annotate):
+    """Enabling spans + the Layer-1 timer, with or without an annotate
+    hook, must not move a single output byte, across the full strategy
+    catalog."""
+    hook = annotate() if annotate is not None else None
     outs = {}
     spans_seen = 0
     for tracing in (False, True):
         set_enabled(tracing)
-        tracer = Tracer() if tracing else None
+        tracer = Tracer(annotate=hook) if tracing else None
         set_tracer(tracer)
         rep = Replica("inert")
         for c in _contribs(4):
@@ -287,6 +303,8 @@ def test_all_26_strategies_byte_identical_with_tracing_on():
     assert len(outs[True]) == 26
     assert outs[True] == outs[False]
     assert spans_seen > 0                        # tracing actually ran
+    if hook is not None:
+        assert hook.entered == spans_seen
 
 
 def test_20_orderings_identical_aggregates_and_bytes():
