@@ -103,6 +103,7 @@ from repro.core.hashing import pytree_digest, tensor_digest, tree_nbytes
 from repro.obs import CounterView, MetricsRegistry, span
 from repro.strategies import get_strategy
 from repro.strategies.base import run_fold, Strategy
+from repro.strategies.select import recording as record_quantile_paths
 
 _DOMAIN_LEAF = b"repro/engine/leaf-subroot/v2"
 _DOMAIN_MODEL = b"repro/engine/model-subroot/v2"
@@ -898,8 +899,14 @@ def execute_plan(plan: MergePlan, contribs: Optional[Sequence[Any]], *,
                                               fuse=kernel_fuse):
                     approximate = False
                     if len(group) == 1:
-                        o, a = _execute_leaf(strat, plan, group[0],
-                                             leaf_of, base_leaves, cache)
+                        with record_quantile_paths() as taken:
+                            o, a = _execute_leaf(strat, plan, group[0],
+                                                 leaf_of, base_leaves,
+                                                 cache)
+                        for path in dict.fromkeys(taken):
+                            cache.obs.counter(
+                                "exact_quantile_leaves_total").inc(
+                                    path=path)
                         out, auxs = [o], [a]
                     else:
                         out, auxs, approximate = _execute_batch(

@@ -1,11 +1,12 @@
 """Two-pass histogram trim-quantile + flat-batch TIES merge kernels.
 
-The per-leaf `ops.ties_merge` path computed its trim threshold with a
-sort (`jnp.quantile`) — a global operation that blocks batching: every
-leaf needed its own sort over k x p elements before the fused merge
-kernel could launch, so TIES never joined the engine's one-launch-per-
-batch flat dispatch. This module replaces the sort with the catalog's
-histogram trim (`strategies.catalog._hist_quantile` math, bit-for-bit):
+The exact trim threshold (`ops.ties_merge(trim_method="quantile")`,
+`strategies.select.exact_quantile_rows`) is a per-row order statistic
+over all of a leaf's k x p elements: every leaf needs its own selection
+before the fused merge kernel can launch, so the exact trim never joins
+the engine's one-launch-per-batch flat dispatch. This module uses the
+catalog's histogram trim instead (`strategies.catalog._hist_quantile`
+math, bit-for-bit), an approximate threshold and so a different result:
 
   pass A  max|tau| per leaf, accumulated over the leaf's blocks (exact:
           max is associative, so blockwise = global bitwise)

@@ -29,6 +29,7 @@ from repro.kernels.nary_accum import nary_accum_pallas
 from repro.kernels.quant import quant_nary_pallas
 from repro.kernels.slerp import slerp_pallas
 from repro.kernels.ties import ties_pallas
+from repro.strategies.select import exact_quantile_rows
 
 
 def _defaults(block: Optional[int],
@@ -178,8 +179,9 @@ def ties_merge(contribs, base=None, trim: float = 0.2, *,
     """Fused TIES. `trim_method="histogram"` (default) resolves the trim
     threshold with the sort-free two-pass histogram kernel — the same
     path the engine's flat-batch dispatch uses; `"quantile"` keeps the
-    exact sort-based threshold (one `jnp.quantile` per leaf, blocks
-    batching)."""
+    exact threshold, `jnp.quantile`'s bit for bit (one counting
+    selection per leaf, `strategies.select`; a per-row statistic, so
+    it blocks batching)."""
     block, interpret = _defaults(block, interpret)
     ls, lb, treedef = _per_leaf(contribs, base)
     outs = []
@@ -196,11 +198,11 @@ def ties_merge(contribs, base=None, trim: float = 0.2, *,
     for s, b in zip(ls, lb):
         sp, n = pad_stacked(s, block)
         bp, _ = pad_flat(b, block)
-        # global (sort-based) trim thresholds, fp32, on the unpadded region
+        # exact per-row trim thresholds, fp32, on the unpadded region
         # (must match the kernel's fp32 tau exactly at the boundary)
-        thr = jnp.quantile(
+        thr = exact_quantile_rows(
             jnp.abs(sp[:, :n] - bp[None, :n]),
-            trim, axis=1).astype(jnp.float32).reshape(-1, 1)
+            trim).astype(jnp.float32).reshape(-1, 1)
         out = ties_pallas(sp, bp[None, :], thr, block=block,
                           interpret=interpret)
         outs.append(_unpad(out, n, b.shape, s.dtype))

@@ -235,8 +235,8 @@ def dryrun_merge_cell(arch: str, *, k: int = 4, strategy: str = "ties",
     merge of full model parameters on the production mesh. The merge is
     elementwise over the parameter shards (the CRDT wrapper moves no
     tensors), so the bound is HBM bandwidth — except for exact-quantile
-    TIES trims, whose global sort is the baseline bottleneck the
-    histogram trim removes (§Perf)."""
+    TIES trims, whose per-row selection over a whole leaf is a global
+    operation the histogram trim removes (§Perf)."""
     from repro.strategies import get_strategy
     cfg = get_config(arch)
     mesh = make_production_mesh(multi_pod=multi_pod)

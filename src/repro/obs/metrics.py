@@ -128,6 +128,11 @@ declare("engine_quant_leaves_merged_total", "counter",
 declare("resolve_fold_updates_total", "counter",
         "Contributions folded into cached accumulators by prefix-fold "
         "resumption (per EngineCache)", deterministic=True)
+declare("exact_quantile_leaves_total", "counter",
+        "Leaves whose exact quantile (TIES quantile trim, "
+        "model_breadcrumbs, emr) the executor took, by path: select "
+        "(counting descent) or sort (jnp.quantile fallback)",
+        labels=("path",), deterministic=True)
 declare("hash_bytes_total", "counter",
         "Bytes hashed by host SHA-256, by call site: the content id in "
         "contribute, the planner's leaf digests in contrib_meta, the "

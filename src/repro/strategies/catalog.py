@@ -42,6 +42,7 @@ import jax.numpy as jnp
 
 from repro.strategies.base import (
     LeafFold, leafwise, register, run_fold, Strategy)
+from repro.strategies.select import exact_quantile_rows
 
 EPS = 1e-12
 
@@ -171,10 +172,11 @@ def _regression_mean(s, b, eps=1e-8, **kw):
 def _hist_quantile(a, q, bins=512):
     """Approximate per-row quantile of |values| via a fixed histogram.
 
-    Shard-friendly alternative to the exact sort: a max-reduce, one
-    scatter-add of bucket indices, and a 512-wide cumsum — no global sort
-    of p elements (the §Perf-optimized trim for distributed TIES; error
-    <= max|tau|/bins).
+    Shard-friendly alternative to the exact selection
+    (`select.exact_quantile_rows`): a max-reduce, one scatter-add of
+    bucket indices, and a 512-wide cumsum — no per-row order statistic
+    over p elements (the §Perf-optimized trim for distributed TIES;
+    error <= max|tau|/bins).
     """
     amax = jnp.max(a, axis=1, keepdims=True) + 1e-12
     idx = jnp.clip((a / amax * bins).astype(jnp.int32), 0, bins - 1)
@@ -195,7 +197,7 @@ def _trim_mask(tau_flat, trim, method="quantile"):
     if method == "histogram":
         q = _hist_quantile(a, trim)
     else:
-        q = jnp.quantile(a, trim, axis=1, keepdims=True)
+        q = exact_quantile_rows(a, trim)
     return (a >= q).astype(tau_flat.dtype)
 
 
@@ -276,8 +278,8 @@ def _della(s, b, key, p_min=0.2, p_max=0.8, **kw):
 def _model_breadcrumbs(s, b, beta=0.1, gamma=0.1, **kw):
     tau = _fl(s - b)
     a = jnp.abs(tau)
-    qlo = jnp.quantile(a, beta, axis=1, keepdims=True)
-    qhi = jnp.quantile(a, 1.0 - gamma, axis=1, keepdims=True)
+    qlo = exact_quantile_rows(a, beta)
+    qhi = exact_quantile_rows(a, 1.0 - gamma)
     mask = ((a >= qlo) & (a <= qhi)).astype(tau.dtype)
     merged = jnp.mean(tau * mask, axis=0)
     return b + merged.reshape(s.shape[1:])
@@ -288,7 +290,7 @@ def _emr(s, b, trim=0.1, **kw):
     elected = jnp.sign(jnp.sum(tau, axis=0, keepdims=True))
     mask = (jnp.sign(tau) == elected).astype(tau.dtype)
     m = jnp.sum(tau * mask, axis=0) / jnp.maximum(jnp.sum(mask, axis=0), 1.0)
-    q = jnp.quantile(jnp.abs(m), trim)
+    q = exact_quantile_rows(jnp.abs(m)[None], trim)[0, 0]
     m = m * (jnp.abs(m) >= q)
     rho = jnp.mean(_norms(s - b)) / (jnp.linalg.norm(m) + EPS)
     return b + (rho * m).reshape(s.shape[1:])
