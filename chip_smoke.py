@@ -229,7 +229,8 @@ def phase_kernel(kernel: str, spec: MergeSpec, contribs, ids, base,
     plan = engine.plan_for(contribs, contrib_ids=ids, base=base, spec=spec)
     strat = get_strategy(spec.strategy)
     groups = engine._dispatch_groups(
-        strat, list(plan.tasks), max(t.stacked_nbytes for t in plan.tasks),
+        strat, list(plan.tasks), None,
+        max(t.stacked_nbytes // t.k for t in plan.tasks),
         fuse=engine._kernel_route(strat, spec.cfg_dict()) is not None)
     fused = [t for g in groups if len(g) > 1 for t in g]
     n_groups = sum(1 for g in groups if len(g) > 1)

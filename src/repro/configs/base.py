@@ -48,6 +48,26 @@ class MoEConfig:
     # every `interval`-th layer is MoE (1 = all layers); offset selects which.
     interval: int = 1
     offset: int = 0
+    # renormalise the top-k gates to sum to 1 (DeepSeek-V2 publishes false)
+    norm_topk_prob: bool = True
+    # the expert-parallel share: routed experts [expert_start,
+    # expert_start + experts_held) are held here (0 = all). The router
+    # keeps all `num_experts` outputs; tokens routed to experts held
+    # elsewhere get no routed output from this share.
+    expert_start: int = 0
+    experts_held: int = 0
+
+    def __post_init__(self):
+        start, n = self.held
+        if start < 0 or n < 0 or start + n > self.num_experts:
+            raise ValueError(f"experts [{start}, {start + n}) are not a "
+                             f"range of the {self.num_experts} routed "
+                             "experts")
+
+    @property
+    def held(self) -> Tuple[int, int]:
+        """(first routed expert held here, how many are held)."""
+        return self.expert_start, self.experts_held or self.num_experts
 
 
 @dataclass(frozen=True)

@@ -88,6 +88,7 @@ default cache — prefer the per-replica methods in new code.
 from __future__ import annotations
 
 import hashlib
+import math
 from collections import OrderedDict
 from dataclasses import dataclass
 from typing import (Any, Dict, List, NamedTuple, Optional, Sequence,
@@ -696,6 +697,7 @@ class EngineCache:
         self.stats.clear()
         self.peak_stacked = 0
         self.obs.gauge("engine_peak_stacked_bytes").set(0)
+        self.obs.gauge("engine_working_set_bytes").set(0)
 
     def note_stacked(self, nbytes: int) -> None:
         self.peak_stacked = max(self.peak_stacked, nbytes)
@@ -793,13 +795,16 @@ def execute_plan(plan: MergePlan, contribs: Optional[Sequence[Any]], *,
     every task is already cached (the zero-fetch re-resolve path).
     Live stacked memory is bounded: the executor materialises one
     leaf's [k, ...] slice stack (or one fused batch — whose per-leaf
-    stacks plus concatenated copy are both transiently live, so the
+    pieces plus concatenated rows are both transiently live, so the
     batch byte cap `max_batch_bytes` defaults to the largest single
-    leaf's stack, keeping the batched peak within ~2 leaves' worth) at
-    a time — never the k full model copies the legacy path stacks. The
-    executor waits for each dispatch before it builds the next: on an
-    asynchronous device (a TPU) the host would otherwise enqueue many
-    dispatches ahead, and all their transients would be live at once.
+    leaf's stack at the batch's own k, keeping the batched peak within
+    ~2 leaves' worth, and a batch's leaves the same as another leaf's
+    k grows) at a time — never the k full model copies the legacy path
+    stacks. The executor waits for each dispatch before it builds the
+    next (and a chain too large for what is free runs in waited-for
+    blocks: `_chain_blocks`): on an asynchronous device (a TPU) the host
+    would otherwise enqueue many dispatches ahead, and all their
+    transients would be live at once.
 
     `pallas=True` routes linear-family batches through the fused
     `kernels/nary_accum` Pallas kernel (fp32 accumulation; validated to
@@ -889,13 +894,12 @@ def execute_plan(plan: MergePlan, contribs: Optional[Sequence[Any]], *,
                 cache.put(t.sub_root, val,
                           int(val.nbytes) + int(acc.nbytes), aux=acc)
             if misses:
-                if max_batch_bytes is None:
-                    max_batch_bytes = max(t.stacked_nbytes
-                                          for t in plan.tasks)
+                leaf_bytes = max(t.stacked_nbytes // t.k
+                                 for t in plan.tasks)
                 kernel_fuse = pallas and \
                     _kernel_route(strat, cfg) is not None
                 for group in _dispatch_groups(strat, misses,
-                                              max_batch_bytes,
+                                              max_batch_bytes, leaf_bytes,
                                               fuse=kernel_fuse):
                     approximate = False
                     if len(group) == 1:
@@ -991,13 +995,14 @@ def plan_needed_ids(plan: MergePlan,
 
 
 def _dispatch_groups(strat: Strategy, misses: List[LeafTask],
-                     max_batch_bytes: int, *,
+                     max_batch_bytes: Optional[int], leaf_bytes: int, *,
                      fuse: bool = False) -> List[List[LeafTask]]:
     """Partition missed tasks into dispatches. Elementwise strategies
     fuse same-dtype leaves (flattened + concatenated) up to the batch
-    byte cap; everything else runs one leaf per dispatch. Under sparse
-    contributions only leaves with the SAME ordered contributor subset
-    fuse — a [k_i, N] batch has one k_i.
+    byte cap (by default `leaf_bytes`, the plan's largest leaf, times
+    the batch's k); everything else runs one leaf per dispatch. Under
+    sparse contributions only leaves with the SAME ordered contributor
+    subset fuse — a [k_i, N] batch has one k_i.
 
     `fuse=True` forces fusing for strategies that are not elementwise-
     batchable but have a kernel-frontier flat-batch route (histogram
@@ -1010,7 +1015,9 @@ def _dispatch_groups(strat: Strategy, misses: List[LeafTask],
     by_dtype: Dict[Any, List[LeafTask]] = {}
     for t in misses:
         by_dtype.setdefault((t.dtype, t.contributors), []).append(t)
-    for tasks in by_dtype.values():
+    for (_, contributors), tasks in by_dtype.items():
+        cap = max_batch_bytes if max_batch_bytes is not None \
+            else leaf_bytes * len(contributors)
         # largest-first packing: the big leaves that fill a batch alone
         # go first, so the many small leaves behind them still fuse
         # instead of being fragmented by an oversized neighbour
@@ -1020,7 +1027,7 @@ def _dispatch_groups(strat: Strategy, misses: List[LeafTask],
         cur: List[LeafTask] = []
         cur_bytes = 0
         for t in tasks:
-            if cur and cur_bytes + t.stacked_nbytes > max_batch_bytes:
+            if cur and cur_bytes + t.stacked_nbytes > cap:
                 groups.append(cur)
                 cur, cur_bytes = [], 0
             cur.append(t)
@@ -1056,16 +1063,163 @@ def _execute_leaf(strat: Strategy, plan: MergePlan, task: LeafTask,
         return _leaf_seq_fold(strat, slices, base_leaves, i, plan.seed,
                               cfg), None
     b = _base_leaf(base_leaves, i, slices[0])
+    kw = {**strat.defaults, **cfg}
     if strat.fold is not None and ki >= strat.fold.min_k:
         # drive the canonical fold directly (identical math to leaf_fn,
         # which is run_fold over the same inputs) to retain the
-        # accumulator for later resumption
-        kw = dict(strat.defaults)
-        kw.update(cfg)
-        return run_fold(strat.fold, slices, b, **kw)
+        # accumulator for later resumption; waited for step by step, it
+        # holds buffers of one contribution's leaf, whatever k is
+        planned = _chain_blocks(task.shape, task.stacked_nbytes // ki,
+                                slices[0])
+        return _in_blocks(
+            cache, plan.strategy, task.path, 1, planned,
+            lambda blocks: _fold_blocks(strat.fold, slices, b, blocks,
+                                        **kw))
+    blocked = strat.blocked(**kw) if strat.blocked is not None else None
+    if blocked is not None:
+        # blocks are picked before the stack is built: it counts in the
+        # working set, not in what is free
+        planned = _chain_blocks(task.shape, task.stacked_nbytes,
+                                slices[0], held=1, first=_THRESHOLD_STACKS)
+        return _in_blocks(
+            cache, plan.strategy, task.path, 1, planned,
+            lambda blocks: blocked(jnp.stack(slices), b, blocks)), None
     stacked = jnp.stack(slices)
     return strat.apply_leaf(stacked, b, leaf_index=i, seed=plan.seed,
                             **cfg), None
+
+
+# Buffers of its stack's size ([k, ...] of its leaf or fused batch) that
+# a chain of eager ops holds at once when the host dispatches it ahead
+# of the device. Measured on the v5e: 16.0 for TIES on a whole
+# [3, 4, 8, 2048, 1408] expert stack, 16.7 for a k=2 task-arithmetic
+# fold over a fused [2, N] stack. A fold waited for step by step
+# (`_fold_blocks`) is reckoned at as many buffers of one contribution's
+# leaf: a step holds the accumulator, the contribution and the base in
+# float32, their difference and the new accumulator, 10 buffers of a
+# bfloat16 leaf.
+_CHAIN_STACKS = 16
+# TIES's threshold stage, run whole before its blocks: the stack, s - b,
+# the magnitudes and the selection's counts
+_THRESHOLD_STACKS = 4
+
+
+def _in_blocks(cache: EngineCache, strategy: str, path: str, leaves: int,
+               planned: Tuple[List[Tuple[slice, ...]], int], run):
+    """`run(blocks)` in the blocks `_chain_blocks` planned, with their
+    working set; a run in more than one block is spanned and counted."""
+    blocks, working_set = planned
+    cache.obs.gauge("engine_working_set_bytes").set_max(working_set)
+    if len(blocks) == 1:
+        return run(blocks)
+    with span("engine.slices", path=path, leaves=leaves,
+              slices=len(blocks), working_set_bytes=working_set):
+        out = run(blocks)
+    cache.obs.counter("sliced_leaves_total").inc(leaves, strategy=strategy)
+    cache.obs.counter("leaf_slices_total").inc(len(blocks),
+                                               strategy=strategy)
+    return out
+
+
+def _chain_blocks(shape: Tuple[int, ...], stack: int, like, *,
+                  held: int = 0, first: int = 0
+                  ) -> Tuple[List[Tuple[slice, ...]], int]:
+    """The blocks of the leading axes of `shape` in which to run a
+    chain over a stack of `stack` bytes (`[()]`: whole), and its
+    working set reckoned in bytes: `held` stacks kept whole beside one
+    block's chain of `_CHAIN_STACKS` stacks, or a stage of `first`
+    stacks run whole before the blocks, whichever is larger.
+
+    Whole where that fits half of what the device holding `like` has
+    free (`bytes_limit` less `bytes_in_use`), or where the device
+    reports neither (a CPU); else the fewest blocks, a power of two, up
+    to `_CHAIN_STACKS` (beyond, one block's chain is below a stack),
+    that fit it. The other half holds the outputs of the leaves still
+    to run and what the allocator cannot place. A power of two, so that
+    the block shapes, compiled once, change only where what is free
+    halves or doubles.
+
+    Only elementwise chains, bit for bit the same block by block, have
+    blocks: the linear family's folds (`Strategy.fold`) and TIES's
+    quantile trim (`Strategy.blocked`). DARE's per-leaf random stream
+    and SLERP's whole-leaf norms would change bits, so those, as every
+    other strategy, run whole-leaf."""
+    n = math.prod(shape)
+
+    def reckon(blocks):
+        largest = max(math.prod(_block_shape(shape, blk))
+                      for blk in blocks)
+        return max(first * stack,
+                   held * stack + _CHAIN_STACKS * stack * largest // n)
+
+    blocks = [()]
+    working_set = reckon(blocks)
+    free = _free_bytes(like)
+    parts = 1
+    while free is not None and working_set > free // 2 \
+            and parts < _CHAIN_STACKS:
+        parts *= 2
+        blocks = leading_blocks(shape, parts)
+        working_set = reckon(blocks)
+    return blocks, working_set
+
+
+def _free_bytes(x) -> Optional[int]:
+    """`bytes_limit` less `bytes_in_use` of the device holding `x`,
+    where it reports them."""
+    devices = getattr(x, "devices", None)
+    if devices is None:
+        return None
+    stats = next(iter(devices())).memory_stats()
+    if not stats or "bytes_limit" not in stats:
+        return None
+    return int(stats["bytes_limit"]) - int(stats.get("bytes_in_use", 0))
+
+
+def _fold_blocks(fold, slices, b, blocks, **kw):
+    """`run_fold` over the leaves `slices` (the contributions') and the
+    base leaf `b`: one block of their leading axes at a time and, in a
+    block, one contribution at a time, each step waited for before the
+    next is dispatched, so that what the fold holds does not grow with
+    k; the blocks' values and accumulators joined in row-major order.
+    The steps are `run_fold`'s and the fold is elementwise, so the bits
+    are the whole run's."""
+    vals, accs = [], []
+    for blk in blocks:
+        part = (lambda x: x[blk]) if blk else (lambda x: x)
+        bb, acc = part(b), None
+        for x in slices[:-1]:
+            acc = jax.block_until_ready(run_fold(
+                fold, [part(x)], bb, acc=acc, finalize=False, **kw)[1])
+        val, acc = jax.block_until_ready(run_fold(
+            fold, [part(slices[-1])], bb, acc=acc, k=len(slices), **kw))
+        vals.append(val)
+        accs.append(acc)
+    if len(blocks) == 1:
+        return vals[0], accs[0]
+    return (jnp.concatenate([v.reshape(-1) for v in vals]).reshape(b.shape),
+            jnp.concatenate([a.reshape(-1) for a in accs]).reshape(b.shape))
+
+
+def leading_blocks(shape: Tuple[int, ...], n: int
+                   ) -> List[Tuple[slice, ...]]:
+    """About `n` rectangular blocks of an array of `shape` along its
+    leading axes, in row-major order (their flattened contents,
+    concatenated, are the array's): contiguous ranges of axis 0 whose
+    sizes differ by one at most; where axis 0 is shorter than `n`,
+    each of its entries split along the next axis the same way."""
+    d0 = shape[0]
+    if n <= d0 or len(shape) == 1:
+        n = max(1, min(n, d0))
+        edges = [d0 * j // n for j in range(n + 1)]
+        return [(slice(lo, hi),) for lo, hi in zip(edges, edges[1:])]
+    inner = leading_blocks(shape[1:], -(-n // d0))
+    return [(slice(j, j + 1),) + blk for j in range(d0) for blk in inner]
+
+
+def _block_shape(shape: Tuple[int, ...], blk: Tuple[slice, ...]
+                 ) -> Tuple[int, ...]:
+    return tuple(s.stop - s.start for s in blk) + tuple(shape[len(blk):])
 
 
 def _leaf_seq_fold(strat, slices, base_leaves, i, seed, cfg):
@@ -1198,10 +1352,13 @@ def _execute_batch(strat: Strategy, plan: MergePlan, group: List[LeafTask],
                    ) -> Tuple[List[Any], List[Any], bool]:
     """Fused dispatch over same-dtype, same-contributor-subset
     elementwise leaves: flatten each leaf's k_i slices, concatenate
-    along the element axis, apply the leaf function ONCE on [k_i, N],
-    slice the outputs back. Elementwise leaf functions reduce only over
-    the k axis, so per-element arithmetic — and therefore output bytes —
-    is identical to leaf-at-a-time execution. Returns (outputs, auxs,
+    along the element axis into one row per contribution, apply the
+    leaf function ONCE on the k_i rows of N (a fold over the rows, in
+    blocks of them where its chain would not fit what is free:
+    `_chain_blocks`), slice the outputs back. Elementwise leaf
+    functions reduce only over the k axis, so per-element arithmetic —
+    and therefore output bytes — is identical to leaf-at-a-time
+    execution. Returns (outputs, auxs,
     approximate): auxs are per-leaf fold accumulator slices for
     incremental strategies (sliced from the batch accumulator —
     elementwise, so bitwise equal to per-leaf folds); approximate=True
@@ -1215,14 +1372,17 @@ def _execute_batch(strat: Strategy, plan: MergePlan, group: List[LeafTask],
                                cache)
         if routed is not None:
             return routed
-    stacked = jnp.concatenate(
-        [jnp.stack([leaf_of(j, t).reshape(-1) for j in contributors])
-         for t in group], axis=1)
-    # the per-leaf stacks and the concatenated copy are both live while
-    # concatenate runs: account 2x, not just the output
-    cache.note_stacked(2 * int(stacked.nbytes))
+    # each contribution's leaves, flattened and joined: a row of the
+    # batch, of a shape that does not depend on k
+    rows = [jnp.concatenate([leaf_of(j, t).reshape(-1) for t in group])
+            for j in contributors]
+    n = rows[0].shape[0]
+    stack = sum(int(r.nbytes) for r in rows)
+    # a row's pieces and the joined row are both live while concatenate
+    # runs: account 2x, not just the output
+    cache.note_stacked(2 * stack)
     if base_leaves is None:
-        b = jnp.zeros(stacked.shape[1:], stacked.dtype)
+        b = jnp.zeros((n,), rows[0].dtype)
     else:
         b = jnp.concatenate([jnp.asarray(base_leaves[t.index]).reshape(-1)
                              for t in group])
@@ -1230,28 +1390,33 @@ def _execute_batch(strat: Strategy, plan: MergePlan, group: List[LeafTask],
     merged = None
     acc = None
     if pallas:
-        merged = _nary_pallas_batch(strat, stacked, b, ki, cfg, cache)
+        merged = _nary_pallas_batch(strat, jnp.stack(rows), b, ki, cfg,
+                                    cache)
         approximate = merged is not None
     if merged is None:
         if strat.fold is not None and ki >= strat.fold.min_k:
             kw = dict(strat.defaults)
             kw.update(cfg)
-            merged, acc = run_fold(strat.fold, stacked, b, **kw)
+            # the rows are built, so they count in what is in use; the
+            # fold, waited for step by step, holds buffers of one row
+            planned = _chain_blocks((n,), stack // ki, rows[0])
+            merged, acc = _in_blocks(
+                cache, plan.strategy, group[0].path, len(group), planned,
+                lambda blocks: _fold_blocks(strat.fold, rows, b, blocks,
+                                            **kw))
         else:
-            merged = strat.apply_leaf(stacked, b,
+            merged = strat.apply_leaf(jnp.stack(rows), b,
                                       leaf_index=group[0].index,
                                       seed=plan.seed, **cfg)
     outs: List[Any] = []
     auxs: List[Any] = []
     off = 0
     for t in group:
-        n = 1
-        for d in t.shape:
-            n *= d
-        outs.append(merged[off:off + n].reshape(t.shape))
-        auxs.append(acc[off:off + n].reshape(t.shape)
+        size = math.prod(t.shape)
+        outs.append(merged[off:off + size].reshape(t.shape))
+        auxs.append(acc[off:off + size].reshape(t.shape)
                     if acc is not None else None)
-        off += n
+        off += size
     return outs, auxs, approximate
 
 

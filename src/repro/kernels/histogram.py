@@ -5,8 +5,9 @@ The exact trim threshold (`ops.ties_merge(trim_method="quantile")`,
 over all of a leaf's k x p elements: every leaf needs its own selection
 before the fused merge kernel can launch, so the exact trim never joins
 the engine's one-launch-per-batch flat dispatch. This module uses the
-catalog's histogram trim instead (`strategies.catalog._hist_quantile`
-math, bit-for-bit), an approximate threshold and so a different result:
+catalog's histogram trim instead (the threshold math of
+`strategies.catalog._ties_nd_histogram`, bit-for-bit), an approximate
+threshold and so a different result:
 
   pass A  max|tau| per leaf, accumulated over the leaf's blocks (exact:
           max is associative, so blockwise = global bitwise)
@@ -94,7 +95,7 @@ def _hist_kernel(leaf_ref, first_ref, len_ref, x_ref, base_ref, amax_ref,
     leaf = leaf_ref[i]
     valid = len_ref[leaf] - (i - first_ref[leaf]) * block
     a = jnp.abs(x_ref[...] - base_ref[...])              # [k, B]
-    # catalog._hist_quantile binning, verbatim: (a / amax * bins) as i32
+    # catalog._ties_nd_histogram binning, verbatim: (a / amax * bins)
     idx = jnp.clip((a / amax_ref[...] * bins).astype(jnp.int32),
                    0, bins - 1)
     k = a.shape[0]
@@ -198,8 +199,8 @@ def hist_thresholds(counts, lengths, amax, trim: float, bins: int):
 
     `counts`: [L, k, bins] fp32 integer counts; `lengths`: [L] true
     (unpadded) leaf lengths; `amax`: [L, k] (already + 1e-12). The cdf /
-    argmax / scale sequence is `catalog._hist_quantile` verbatim so the
-    resolved thresholds match the eager oracle bitwise.
+    argmax / scale sequence is `catalog._ties_nd_histogram`'s verbatim,
+    so the resolved thresholds match the eager oracle bitwise.
     """
     cdf = jnp.cumsum(counts, axis=2) / \
         lengths.astype(jnp.float32)[:, None, None]

@@ -42,7 +42,7 @@ def nary_accum_ref(stacked, base, weights):
 
 def hist_threshold_ref(stacked, base, trim=0.2, bins=512):
     """Per-contribution trim thresholds, `strategies.catalog
-    ._hist_quantile` verbatim (same op order, fp32). Exact regardless
+    ._ties_nd_histogram`'s verbatim (same op order, fp32). Exact regardless
     of layout: the max is associative and the counts are integers in
     fp32, so the flat-batch kernel's per-block passes must reproduce
     these bits."""

@@ -12,6 +12,14 @@ Sharding: tokens are grouped [G, s, ...] with G on the data axes; expert
 tensors [E, ...] carry 'ep' (model axis). The g-sharded -> e-sharded
 constraint between dispatch and expert compute is where GSPMD inserts the
 MoE all-to-all.
+
+Expert-parallel share (`MoEConfig.expert_start` / `experts_held`): the
+router keeps all `num_experts` outputs and every token is routed over
+all of them, but the expert stacks hold only this share's experts, and
+only assignments to those are dispatched. Positions within an expert
+and its capacity are those of the uncut layer, so summing every share's
+routed output gives the uncut layer's. Nothing stands in for the
+experts held elsewhere; the shared experts are every share's own.
 """
 from __future__ import annotations
 
@@ -28,16 +36,14 @@ def moe_def(cfg: ModelConfig) -> dict:
     m = cfg.moe
     d = cfg.d_model
     f = m.d_ff_expert
+    e = m.held[1]
     scale = 0.02
     p = {
         "router": PDef((d, m.num_experts), (None, None), scale=scale),
         "experts": {
-            "w_gate": PDef((m.num_experts, d, f), ("ep", "fsdp", None),
-                           scale=scale),
-            "w_up": PDef((m.num_experts, d, f), ("ep", "fsdp", None),
-                         scale=scale),
-            "w_down": PDef((m.num_experts, f, d), ("ep", None, "fsdp"),
-                           scale=scale),
+            "w_gate": PDef((e, d, f), ("ep", "fsdp", None), scale=scale),
+            "w_up": PDef((e, d, f), ("ep", "fsdp", None), scale=scale),
+            "w_down": PDef((e, f, d), ("ep", None, "fsdp"), scale=scale),
         },
     }
     if m.num_shared_experts:
@@ -52,7 +58,8 @@ def _router(p, x, m: MoEConfig):
                         p["router"].astype(jnp.float32))
     probs = jax.nn.softmax(logits, axis=-1)
     gates, idx = jax.lax.top_k(probs, m.top_k)
-    gates = gates / jnp.maximum(gates.sum(-1, keepdims=True), 1e-9)
+    if m.norm_topk_prob:
+        gates = gates / jnp.maximum(gates.sum(-1, keepdims=True), 1e-9)
     # load-balancing aux loss (Switch-style)
     density = jnp.mean(
         jax.nn.one_hot(idx[..., 0], m.num_experts, dtype=jnp.float32),
@@ -92,6 +99,8 @@ def moe_einsum(p, x, cfg: ModelConfig, compute_dtype):
     pos = jnp.sum(pos * flat, axis=-1).reshape(gdim, s, m.top_k)  # [G,s,k]
     keep = pos < c
     pos_oh = jax.nn.one_hot(pos, c, dtype=compute_dtype) * keep[..., None]
+    start, held = m.held
+    onehot = onehot[..., start:start + held]          # the held experts
     # dispatch mask [G, s, E, C] = sum_k onehot_e * onehot_c
     dispatch = jnp.einsum("gske,gskc->gsec",
                           onehot.astype(compute_dtype), pos_oh)
@@ -116,13 +125,19 @@ def moe_gather(p, x, cfg: ModelConfig, compute_dtype):
     flat = onehot_e.reshape(gdim, s * m.top_k, m.num_experts)
     pos = jnp.cumsum(flat, axis=1) - flat
     pos = jnp.sum(pos * flat, axis=-1).reshape(gdim, s, m.top_k)
-    keep = pos < c                                               # [G,s,k]
+    # an assignment is dispatched here when it is within capacity and
+    # its expert is held here; idx becomes the index in the held stack
+    start, held = m.held
+    local = idx - start
+    mine = (local >= 0) & (local < held)
+    keep = (pos < c) & mine                                      # [G,s,k]
+    idx = jnp.where(mine, local, 0)
 
     token_id = jnp.broadcast_to(jnp.arange(s)[None, :, None],
                                 (gdim, s, m.top_k))
     # scatter token ids into the [E, C] dispatch table (drop over capacity)
     def scatter_group(eidx, posg, tidg, keepg):
-        tbl = jnp.zeros((m.num_experts, c), jnp.int32)
+        tbl = jnp.zeros((held, c), jnp.int32)
         iidx = jnp.stack([eidx.reshape(-1),
                           jnp.where(keepg, posg, c).reshape(-1)], -1)
         return tbl.at[iidx[:, 0], iidx[:, 1]].set(
@@ -134,7 +149,7 @@ def moe_gather(p, x, cfg: ModelConfig, compute_dtype):
 
     # gather rows: xin[g, e, c] = x[g, table[g, e, c]]
     xin = jax.vmap(lambda xg, tg: xg[tg.reshape(-1)].reshape(
-        m.num_experts, c, d))(x.astype(compute_dtype), table)
+        held, c, d))(x.astype(compute_dtype), table)
     xin = xin * slot_used[..., None].astype(compute_dtype)
     xin = _shard_expert(xin)
     yout = _expert_ffn(p["experts"], xin, "swiglu", compute_dtype)

@@ -107,6 +107,16 @@ declare("engine_events_total", "counter",
 declare("engine_peak_stacked_bytes", "gauge",
         "High-water mark of stacked contribution bytes live at once",
         deterministic=True)
+declare("engine_working_set_bytes", "gauge",
+        "High-water mark of the working set the executor reckoned for a "
+        "chain it can run in blocks (a linear-family fold, TIES's "
+        "quantile trim)")
+declare("sliced_leaves_total", "counter",
+        "Leaves the executor ran block by block to bound their working "
+        "set, by strategy", labels=("strategy",))
+declare("leaf_slices_total", "counter",
+        "Blocks of the leaves and fused batches the executor ran block "
+        "by block, by strategy", labels=("strategy",))
 declare("engine_cache_resident_bytes", "gauge",
         "Bytes of merge outputs resident in the sub-root cache")
 declare("engine_plan_leaves", "gauge",

@@ -119,6 +119,10 @@ class Strategy:
     # The audit suite proves every declared fold bit-equal to the full
     # recompute at all prefix lengths >= fold.min_k.
     fold: Optional[LeafFold] = None
+    # blocked(**cfg) -> fn(stacked, base, blocks) computing leaf_fn block
+    # by block along the leaf's leading axes, bit for bit leaf_fn's, or
+    # None where that cfg has no such form. None = whole-leaf only.
+    blocked: Optional[Callable] = None
 
     def __call__(self, contribs: List[Any], *, base: Any = None,
                  seed: int = 0, **cfg) -> Any:

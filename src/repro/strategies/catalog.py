@@ -169,49 +169,57 @@ def _regression_mean(s, b, eps=1e-8, **kw):
 # ---------------------------------------------------------------- sparse ---
 
 
-def _hist_quantile(a, q, bins=512):
-    """Approximate per-row quantile of |values| via a fixed histogram.
-
-    Shard-friendly alternative to the exact selection
-    (`select.exact_quantile_rows`): a max-reduce, one scatter-add of
-    bucket indices, and a 512-wide cumsum — no per-row order statistic
-    over p elements (the §Perf-optimized trim for distributed TIES;
-    error <= max|tau|/bins).
-    """
-    amax = jnp.max(a, axis=1, keepdims=True) + 1e-12
-    idx = jnp.clip((a / amax * bins).astype(jnp.int32), 0, bins - 1)
-
-    # fp32 counts: leaves can exceed 2^31 elements (int32 cumsum overflow)
-    def row_counts(row_idx):
-        return jnp.zeros((bins,), jnp.float32).at[row_idx].add(1.0)
-
-    counts = jax.vmap(row_counts)(idx)                   # [k, bins]
-    cdf = jnp.cumsum(counts, axis=1) / jnp.float32(a.shape[1])
-    bucket = jnp.argmax(cdf >= q, axis=1)                # first crossing
-    return (bucket[:, None].astype(a.dtype) / bins) * amax
-
-
-def _trim_mask(tau_flat, trim, method="quantile"):
-    """Keep entries with |tau| >= per-contribution trim quantile."""
-    a = jnp.abs(tau_flat)
-    if method == "histogram":
-        q = _hist_quantile(a, trim)
-    else:
-        q = exact_quantile_rows(a, trim)
-    return (a >= q).astype(tau_flat.dtype)
-
-
 def _ties(s, b, trim=0.2, trim_method="quantile", **kw):
     if trim_method == "histogram":
         return _ties_nd_histogram(s, b, trim)
+    return _ties_quantile(s, b, trim, [()])
+
+
+def _ties_blocked(trim=0.2, trim_method="quantile", **kw):
+    """`_ties` block by block, for the executor to bound its working
+    set on a large leaf: fn(s, b, blocks), `blocks` rectangular index
+    tuples over the leaf's leading axes in row-major order (see
+    `core.engine.leading_blocks`; `[()]`: the whole leaf, `_ties`
+    itself). None for the histogram trim (whole-leaf only)."""
+    if trim_method == "histogram":
+        return None
+    return lambda s, b, blocks: _ties_quantile(s, b, trim, blocks)
+
+
+def _ties_quantile(s, b, trim, blocks):
+    """TIES with the exact quantile trim. The threshold is one per row
+    over the whole leaf; after it every step is elementwise or reduces
+    over k alone, so it runs one block at a time with each block's bits
+    the whole leaf's."""
     tau = _fl(s - b)
-    trimmed = tau * _trim_mask(tau, trim, trim_method)
+    a = jnp.abs(tau)
+    q = exact_quantile_rows(a, trim)
+    if len(blocks) == 1:
+        return b + _ties_elect(tau, a, q).reshape(s.shape[1:])
+    # each step is waited for before the next is dispatched: queued
+    # ahead of the device, every block's buffers would be live at once
+    q = jax.block_until_ready(q)
+    del tau, a
+    out = []
+    for blk in blocks:
+        bb = b[blk]
+        tau = _fl(s[(slice(None),) + blk] - bb)
+        merged = _ties_elect(tau, jnp.abs(tau), q)
+        out.append(jax.block_until_ready(
+            (bb + merged.reshape(bb.shape)).reshape(-1)))
+    return jnp.concatenate(out).reshape(s.shape[1:])
+
+
+def _ties_elect(tau, a, q):
+    """TIES after the threshold `q` ([k, 1]) on flat task vectors
+    `tau` ([k, n]) and their magnitudes `a`: trim, sign election,
+    disjoint mean."""
+    trimmed = tau * (a >= q).astype(tau.dtype)
     elected = jnp.sign(jnp.sum(trimmed, axis=0, keepdims=True))
     agree = (jnp.sign(trimmed) == elected) & (trimmed != 0)
     agree = agree.astype(tau.dtype)
     cnt = jnp.maximum(jnp.sum(agree, axis=0), 1.0)
-    merged = jnp.sum(trimmed * agree, axis=0) / cnt
-    return b + merged.reshape(s.shape[1:])
+    return jnp.sum(trimmed * agree, axis=0) / cnt
 
 
 def _ties_nd_histogram(s, b, trim, bins=512):
@@ -475,19 +483,24 @@ def _genetic_merge(s, b, grid=11, gens=3, reg=0.05, **kw):
 
 def _reg(name, leaf_fn, *, schema, needs_key=False, stochastic=False,
          binary_only=False, category="linear", whole_model=False,
-         elementwise=False, fold=None, **defaults):
+         elementwise=False, fold=None, blocked=None, **defaults):
     register(Strategy(name=name, fn=leafwise(leaf_fn, needs_key=needs_key),
                       stochastic=stochastic, binary_only=binary_only,
                       category=category, defaults=defaults,
                       leaf_fn=leaf_fn, needs_key=needs_key,
                       whole_model=whole_model, elementwise=elementwise,
-                      cfg_schema=dict(schema), fold=fold))
+                      cfg_schema=dict(schema), fold=fold, blocked=blocked))
 
 
 # `elementwise`: the leaf function reduces only over the leading k axis
 # (no per-leaf norms/quantiles/SVD/shape use), so the engine may fuse
 # arbitrarily many leaves into one flattened [k, N] dispatch — same
 # per-element arithmetic, byte-identical output.
+# `blocked`: the leaf function also runs block by block, bit for bit
+# (TIES's quantile trim); the executor uses it, as it uses a `fold`, to
+# bound a large leaf's working set. DARE's per-leaf random stream and
+# SLERP's whole-leaf norms would change bits under blocks: they, like
+# every other strategy without either, run whole-leaf.
 # `whole_model`: population-search and SVD-based strategies whose cost
 # profile is dominated by per-call factorization/search rather than
 # streaming elementwise math; the engine routes them through the legacy
@@ -514,7 +527,7 @@ _reg("dam", _dam, schema={})
 _reg("ada_merging", _ada_merging, schema={"eps": (float, 1e-8)})
 _reg("regression_mean", _regression_mean, schema={"eps": (float, 1e-8)})
 
-_reg("ties", _ties, category="sparse",
+_reg("ties", _ties, category="sparse", blocked=_ties_blocked,
      schema={"trim": (float, 0.2), "trim_method": (str, "quantile")})
 _reg("dare", _dare, needs_key=True, stochastic=True, category="sparse",
      schema={"p": (float, 0.5)})
