@@ -33,11 +33,11 @@ before it is acknowledged. `close()` flushes and releases the storage
 """
 from __future__ import annotations
 
-from typing import Any, Dict, Iterable, Optional
+from typing import Any, Dict, Iterable, Optional, Tuple
 
 from repro.api.spec import MergeSpec
-from repro.core.engine import CacheInfo, EngineCache
-from repro.core.hashing import pytree_digest, tree_nbytes
+from repro.core.engine import CacheInfo, EngineCache, seed_meta
+from repro.core.hashing import pytree_leaf_digests, tree_nbytes
 from repro.core.state import CRDTMergeState
 from repro.core.trust import TrustState
 from repro.obs import MetricsRegistry, span
@@ -114,7 +114,10 @@ class Replica:
         cache key). Pass the pytree's own paths (`leaf_paths_of`) or
         let validation catch a mismatch."""
         with span("layer1.contribute") as sp:
-            eid = element_id or self._content_id(contribution, "contribute")
+            eid, leaf_digests = element_id, None
+            if not eid:
+                eid, leaf_digests = self._content_id(contribution,
+                                                     "contribute")
             sp.set(eid=eid[:16])
             if self._node is not None:
                 self._node.contribute(contribution, element_id=eid,
@@ -123,18 +126,23 @@ class Replica:
                 self._set_state(self._state.add(contribution, self.node_id,
                                                 element_id=eid,
                                                 leaf_paths=leaves))
+            if leaf_digests is not None:
+                # the planner takes the leaf digests the id was made of
+                seed_meta(contribution, eid, leaf_digests, obs=self.obs)
         return eid
 
-    def _content_id(self, tree: Any, site: str) -> str:
-        """Content id of `tree` (hex SHA-256), spanned as
-        `layer1.digest` and counted under `hash_bytes_total{site}` on
-        this replica's registry."""
+    def _content_id(self, tree: Any,
+                    site: str) -> Tuple[str, Dict[str, bytes]]:
+        """Content id of `tree` (hex SHA-256) and the digests of its
+        leaves by path, spanned as `layer1.digest` and counted under
+        `hash_bytes_total{site}` on this replica's registry."""
         nbytes = tree_nbytes(tree)
         with span("layer1.digest", bytes=nbytes) as sp:
-            ref = pytree_digest(tree).hex()
+            digest, leaf_digests = pytree_leaf_digests(tree)
+            ref = digest.hex()
             sp.set(eid=ref[:16])
         self.obs.counter("hash_bytes_total").inc(nbytes, site=site)
-        return ref
+        return ref, leaf_digests
 
     def add(self, contribution: Any, *,
             leaves: Optional[Iterable[str]] = None,
@@ -195,7 +203,7 @@ class Replica:
         """Pin a base model; returns its content ref for
         `MergeSpec(base_ref=...)`. Content-addressed: the ref fully
         determines the bytes, so specs carrying it are portable."""
-        ref = self._content_id(payload, "register_base")
+        ref, _ = self._content_id(payload, "register_base")
         self._bases[ref] = payload
         return ref
 

@@ -191,6 +191,38 @@ _META_MEMO: "OrderedDict[str, ContribMeta]" = OrderedDict()
 _META_MEMO_LIMIT = 1024
 
 
+def _flat_leaves(contribution: Any):
+    """Flatten a contribution as the planner sees it: a `CompressedTree`
+    by its structure, each `CompressedLeaf` kept whole."""
+    if isinstance(contribution, CompressedTree):
+        contribution = compressed_tree_to_structure(contribution)
+    return jax.tree_util.tree_flatten_with_path(contribution,
+                                                is_leaf=_is_qleaf)
+
+
+def _build_meta(flat, treedef, digests: Sequence[bytes]) -> ContribMeta:
+    leaves = [l for _, l in flat]
+    quantized = any(_is_qleaf(l) for l in leaves)
+    return ContribMeta(
+        treedef=treedef,
+        digests=tuple(digests),
+        shapes=tuple(tuple(l.shape) if _is_qleaf(l) else tuple(jnp.shape(l))
+                     for l in leaves),
+        dtypes=tuple(jnp.dtype(l.dtype) if _is_qleaf(l)
+                     else jnp.asarray(l).dtype for l in leaves),
+        paths=tuple(jax.tree_util.keystr(p) for p, _ in flat),
+        scales=tuple(float(l.scale) if _is_qleaf(l) else None
+                     for l in leaves) if quantized else None,
+    )
+
+
+def _memoize(eid: str, meta: ContribMeta) -> ContribMeta:
+    _META_MEMO[eid] = meta
+    while len(_META_MEMO) > _META_MEMO_LIMIT:
+        _META_MEMO.popitem(last=False)
+    return meta
+
+
 def contrib_meta(contribution: Any, *, eid: Optional[str] = None,
                  obs: Optional[MetricsRegistry] = None) -> ContribMeta:
     """Flatten + digest one contribution; memoized by content id.
@@ -202,44 +234,60 @@ def contrib_meta(contribution: Any, *, eid: Optional[str] = None,
     the meta so the plan can account int8 wire bytes and the executor
     can route the batch through the merge-on-arrival kernel.
 
-    A miss (not a memo hit) is spanned as `engine.contrib_meta` and
-    its hashed bytes counted under `hash_bytes_total{site=contrib_meta}`
-    on `obs`, the registry of the replica that asked."""
+    A miss (not a memo hit) is spanned as `engine.contrib_meta`, its
+    hashed bytes counted under `hash_bytes_total{site=contrib_meta}`
+    and the miss under `contrib_meta_total{source=hashed}` on `obs`,
+    the registry of the replica that asked. A contribution whose id
+    `Replica.contribute` computed from its content is memoized there
+    (`seed_meta`), so its first resolve is a hit."""
     if eid is not None and eid in _META_MEMO:
         _META_MEMO.move_to_end(eid)
         return _META_MEMO[eid]
-    if isinstance(contribution, CompressedTree):
-        contribution = compressed_tree_to_structure(contribution)
-    flat, treedef = jax.tree_util.tree_flatten_with_path(
-        contribution, is_leaf=_is_qleaf)
-    leaves = [l for _, l in flat]
-    quantized = any(_is_qleaf(l) for l in leaves)
+    flat, treedef = _flat_leaves(contribution)
     digests: List[bytes] = []
     nbytes = 0
     with span("engine.contrib_meta", eid=eid[:16] if eid else None) as sp:
-        for l in leaves:
+        for _, l in flat:
             dense = _dense_leaf(l, obs=None)
             digests.append(tensor_digest(dense))
             nbytes += tree_nbytes(dense)
         sp.set(bytes=nbytes)
     if obs is not None:
         obs.counter("hash_bytes_total").inc(nbytes, site="contrib_meta")
-    meta = ContribMeta(
-        treedef=treedef,
-        digests=tuple(digests),
-        shapes=tuple(tuple(l.shape) if _is_qleaf(l) else tuple(jnp.shape(l))
-                     for l in leaves),
-        dtypes=tuple(jnp.dtype(l.dtype) if _is_qleaf(l)
-                     else jnp.asarray(l).dtype for l in leaves),
-        paths=tuple(jax.tree_util.keystr(p) for p, _ in flat),
-        scales=tuple(float(l.scale) if _is_qleaf(l) else None
-                     for l in leaves) if quantized else None,
-    )
-    if eid is not None:
-        _META_MEMO[eid] = meta
-        while len(_META_MEMO) > _META_MEMO_LIMIT:
-            _META_MEMO.popitem(last=False)
-    return meta
+        obs.counter("contrib_meta_total").inc(source="hashed")
+    meta = _build_meta(flat, treedef, digests)
+    return _memoize(eid, meta) if eid is not None else meta
+
+
+def seed_meta(contribution: Any, eid: str, leaf_digests: Dict[str, bytes],
+              *, obs: Optional[MetricsRegistry] = None
+              ) -> Optional[ContribMeta]:
+    """Memoize the planner's meta of a contribution from the per-leaf
+    digests its content id `eid` was combined from
+    (`hashing.pytree_leaf_digests`), hashing nothing: treedef, shapes,
+    dtypes and paths come from the pytree, each digest from the map by
+    its leaf's path (the map is in sorted path order, the meta in
+    flatten order). Counted under `contrib_meta_total{source=
+    content_id}` on `obs`.
+
+    Where the digests cannot be shown to be the ones `contrib_meta`
+    would hash, nothing is memoized and None returned: a quantized
+    payload (a `CompressedTree` or any `CompressedLeaf`, whose planner
+    digests describe dequantized values), or a map that does not hold
+    exactly one digest per leaf path. A memo hit is returned as is."""
+    if eid in _META_MEMO:
+        _META_MEMO.move_to_end(eid)
+        return _META_MEMO[eid]
+    flat, treedef = _flat_leaves(contribution)
+    paths = [jax.tree_util.keystr(p) for p, _ in flat]
+    if any(_is_qleaf(l) for _, l in flat) \
+            or len(leaf_digests) != len(paths) \
+            or not all(p in leaf_digests for p in paths):
+        return None
+    if obs is not None:
+        obs.counter("contrib_meta_total").inc(source="content_id")
+    return _memoize(eid, _build_meta(
+        flat, treedef, [leaf_digests[p] for p in paths]))
 
 
 def note_meta(eid: str, paths: Sequence[str], digests: Sequence[bytes],
@@ -268,10 +316,7 @@ def note_meta(eid: str, paths: Sequence[str], digests: Sequence[bytes],
                 if scales is not None and any(s is not None for s in scales)
                 else None),
     )
-    _META_MEMO[eid] = meta
-    while len(_META_MEMO) > _META_MEMO_LIMIT:
-        _META_MEMO.popitem(last=False)
-    return meta
+    return _memoize(eid, meta)
 
 
 def memoized_meta(eid: str) -> Optional[ContribMeta]:

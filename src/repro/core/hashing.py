@@ -15,11 +15,14 @@ Two tiers:
 `hash.fetch` (the copy of the array to host memory) and `hash.sha256`
 (the contiguous copy and the SHA-256). `tree_nbytes` gives the bytes a
 digest of a pytree hashes, for its caller's `hash_bytes_total`.
+`pytree_leaf_digests` returns the per-leaf digests a content id was
+combined from, so the planner can take them instead of hashing the
+same leaves again.
 """
 from __future__ import annotations
 
 import hashlib
-from typing import Tuple
+from typing import Dict, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -53,14 +56,27 @@ def tree_nbytes(tree) -> int:
                for l in jax.tree_util.tree_leaves(tree))
 
 
-def pytree_digest(tree) -> bytes:
-    """SHA-256 of a parameter pytree: leaves hashed, combined in path order."""
+def pytree_leaf_digests(tree) -> Tuple[bytes, Dict[str, bytes]]:
+    """SHA-256 of a parameter pytree together with the `tensor_digest`
+    of each leaf, keyed by its `keystr` path: leaves hashed once,
+    combined in sorted path order. A path two leaves share keeps the
+    last one's digest only, so a caller reusing the digests checks
+    that the map holds one entry per leaf."""
     flat, _ = jax.tree_util.tree_flatten_with_path(tree)
     h = hashlib.sha256()
-    for path, leaf in sorted(flat, key=lambda kv: jax.tree_util.keystr(kv[0])):
-        h.update(jax.tree_util.keystr(path).encode())
-        h.update(tensor_digest(leaf))
-    return h.digest()
+    leaf_digests: Dict[str, bytes] = {}
+    for path, leaf in sorted(((jax.tree_util.keystr(p), l) for p, l in flat),
+                             key=lambda kv: kv[0]):
+        digest = tensor_digest(leaf)
+        h.update(path.encode())
+        h.update(digest)
+        leaf_digests[path] = digest
+    return h.digest(), leaf_digests
+
+
+def pytree_digest(tree) -> bytes:
+    """SHA-256 of a parameter pytree: leaves hashed, combined in path order."""
+    return pytree_leaf_digests(tree)[0]
 
 
 def hexdigest(tree) -> str:

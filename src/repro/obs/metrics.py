@@ -148,6 +148,11 @@ declare("hash_bytes_total", "counter",
         "contribute, the planner's leaf digests in contrib_meta, the "
         "base-leaf digests of a plan, the base ref in register_base",
         labels=("site",))
+declare("contrib_meta_total", "counter",
+        "Planner metas of contributions built, by source: content_id "
+        "(from the leaf digests of the content id contribute computed), "
+        "hashed (a memo miss that hashed the payload)",
+        labels=("source",))
 declare("resolve_layer1_overhead_ms", "histogram",
         "CRDT-side resolve overhead: gate + canonical order + Merkle "
         "root + seed derivation, per resolve (the paper's <0.5 ms claim)",

@@ -3,7 +3,8 @@
 A round is what a replica does when a fine-tune arrives: contribute it,
 retract the oldest, collect the tombstone, resolve. Each step is
 spanned where the work happens (Layer 1, the planner's hashes, the
-executor's waits), each host hash counts its bytes under
+executor's waits); the arrival is hashed once, for its content id,
+whose leaf digests the planner takes. Each host hash counts its bytes under
 `hash_bytes_total{site}` on the replica's registry, and a tracer with
 an `annotate` hook mirrors every span onto another clock in nesting
 order.
@@ -119,17 +120,17 @@ def test_round_spans_names_and_parents(strategy, pin_base):
 
     top = [s.name for s in tracer.spans if s.parent_id is None]
     want = ["layer1.contribute", "layer1.retract", "layer1.gc",
-            "engine.contrib_meta", "engine.plan", "engine.execute"]
+            "engine.plan", "engine.execute"]
     assert top == want
     named = {}
     for s in tracer.spans:
         named.setdefault(s.name, []).append(s)
+    assert "engine.contrib_meta" not in named
     (digest,) = named["layer1.digest"]
     assert parent(digest) == "layer1.contribute"
-    (meta,) = named["engine.contrib_meta"]
     eid = named["layer1.contribute"][0].attrs["eid"]
-    assert digest.attrs["eid"] == meta.attrs["eid"] == eid == eids[-1][:16]
-    assert digest.attrs["bytes"] == meta.attrs["bytes"] == _nbytes(c)
+    assert digest.attrs["eid"] == eid == eids[-1][:16]
+    assert digest.attrs["bytes"] == _nbytes(c)
     assert named["layer1.retract"][0].attrs["eid"] != eid
     assert named["layer1.gc"][0].attrs == {"n": 1}
     n_leaves = len(jax.tree_util.tree_leaves(c))
@@ -140,13 +141,12 @@ def test_round_spans_names_and_parents(strategy, pin_base):
         (base,) = named["engine.base_digest"]
         assert parent(base) == "engine.plan"
         assert base.attrs["bytes"] == _nbytes(_model(0))
-        assert hash_parents == {"layer1.digest", "engine.contrib_meta",
-                                "engine.base_digest"}
-        assert len(fetches) == 3 * n_leaves
+        assert hash_parents == {"layer1.digest", "engine.base_digest"}
+        assert len(fetches) == 2 * n_leaves
     else:
         assert "engine.base_digest" not in named
-        assert hash_parents == {"layer1.digest", "engine.contrib_meta"}
-        assert len(fetches) == 2 * n_leaves
+        assert hash_parents == {"layer1.digest"}
+        assert len(fetches) == n_leaves
     assert len(named["hash.sha256"]) == len(fetches)
     waits = named["engine.wait"]
     assert {parent(s) for s in waits} == {"engine.execute"}
@@ -155,20 +155,21 @@ def test_round_spans_names_and_parents(strategy, pin_base):
 
 @pytest.mark.parametrize("strategy,pin_base", ROUNDS)
 def test_hash_bytes_counted_per_site(strategy, pin_base):
-    """A round hashes the arriving contribution twice (its content id,
-    its planner digests) and, with a pinned base, the base once more;
-    a second resolve of the same set hashes only the base."""
+    """A round hashes the arriving contribution once (its content id,
+    whose leaf digests the planner takes) and, with a pinned base, the
+    base once more; a second resolve of the same set hashes only the
+    base."""
     rep, spec, eids = _setup(strategy, pin_base)
     c = _model(99)
     n = _nbytes(c)
     before = _hashed(rep)
     _round(rep, spec, eids, c)
     after = _hashed(rep)
-    want = {"contribute": n, "contrib_meta": n}
+    want = {"contribute": n}
     if pin_base:
         want["base"] = n
     assert _delta(after, before) == want
-    assert sum(want.values()) == (3 if pin_base else 2) * n
+    assert sum(want.values()) == (2 if pin_base else 1) * n
     rep.resolve(spec, use_cache=False)
     assert _delta(_hashed(rep), after) == ({"base": n} if pin_base else {})
 
