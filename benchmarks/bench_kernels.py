@@ -210,7 +210,7 @@ def gates(quick: bool = True) -> List[Dict]:
     out.append({"gate": "dare_batch_byte_identity", "ok": bool(dident),
                 "value": float(dident), "threshold": 1.0})
 
-    # --- gate 4: int8 merge-on-arrival, zero fp32 dequant round-trips -
+    # --- gate 4: int8 merge-on-arrival bitwise == dequantize-then-merge -
     qt = quant_traffic(k, p)
     qs = [jnp.asarray(rng.integers(-127, 128, (k, n)), jnp.int8)
           for n in lengths]
@@ -226,30 +226,8 @@ def gates(quick: bool = True) -> List[Dict]:
         r = jref(qp, sc, bp[None, :], w.reshape(-1, 1))
         qident &= bool(np.array_equal(np.asarray(o),
                                       np.asarray(r).reshape(-1)[:n]))
-    # engine-level: quantized contributions must merge without EVER
-    # densifying a leaf (dequant_leaves counter stays zero)
-    from repro.core import engine
-    from repro.core.compression import compress_tree
-    rng2 = np.random.default_rng(7)
-    trees = [{"a": jnp.asarray(rng2.standard_normal((8, 33)), jnp.float32),
-              "b": jnp.asarray(rng2.standard_normal(257), jnp.float32)}
-             for _ in range(3)]
-    cts = [compress_tree(t) for t in trees]
-    cache = engine.EngineCache()
-    plan = engine.plan_merge([engine.contrib_meta(c) for c in cts],
-                             "weight_average")
-    engine.execute_plan(plan, cts, use_cache=False, pallas=True,
-                        max_batch_bytes=1 << 20, cache=cache)
-    dequants = int(cache.stats["dequant_leaves"])
-    qleaves = int(
-        cache.obs.counter("engine_quant_leaves_merged_total").value())
-    out.append({
-        "gate": "quant_zero_fp32_roundtrips",
-        "ok": qident and dequants == 0 and qleaves > 0,
-        "value": float(dequants), "threshold": 0.0,
-        "byte_identity": qident, "engine_dequant_leaves": dequants,
-        "engine_quant_leaves_merged_total": qleaves, "traffic": qt,
-    })
+    out.append({"gate": "quant_nary_byte_identity", "ok": qident,
+                "value": float(qident), "threshold": 1.0, "traffic": qt})
     return out
 
 

@@ -17,8 +17,9 @@ Checks, each of which fails the run:
     ways, and resolve to bitwise-equal pytrees;
   * each resolve agrees with `core.resolve.reference_apply` on the same
     device: bitwise, or within the stated tolerance (reported);
-  * the Pallas kernel routes `nary_accum`, `ties_hist` and `quant_nary`
-    dispatch compiled (kernel_dispatch_total counts every fused group)
+  * the library's flat-batch Pallas kernels `nary_accum`, `ties_hist`
+    and `quant_nary`, called through `kernels.ops` on batches of the
+    model's leaves (the merge engine does not call them), run compiled
     and agree with their `kernels/ref.py` oracles within the tolerance.
 
 Exits non-zero, printing no result, when JAX finds no TPU or Pallas
@@ -41,14 +42,12 @@ import jax.numpy as jnp  # noqa: E402
 
 from repro.api import MergeSpec, Replica  # noqa: E402
 from repro.configs import get_config  # noqa: E402
-from repro.core import engine  # noqa: E402
 from repro.core.compression import compress_tree  # noqa: E402
 from repro.core.resolve import reference_apply, seed_from_root  # noqa: E402
-from repro.kernels import ref  # noqa: E402
+from repro.kernels import ops, ref  # noqa: E402
 from repro.kernels.config import kernel_env  # noqa: E402
 from repro.launch.compile_cache import place_compile_cache  # noqa: E402
 from repro.models.model import Model  # noqa: E402
-from repro.strategies import get_strategy  # noqa: E402
 
 ARCH = "phi3-mini-3.8b"
 # 2 of 32 layers: 0.42 G parameters, 0.85 GB a bf16 copy; every leaf
@@ -205,99 +204,117 @@ def phase_resolve(ra, rb, base, name: str) -> dict:
     return res
 
 
-def _stack(contribs, i: int):
-    """[k, n] fp32 rows of leaf i, in canonical contribution order."""
+def _rows(contribs, i: int):
+    """[k, n] rows of leaf i in its own dtype, in canonical order."""
     return jnp.stack([jax.tree_util.tree_leaves(c)[i].reshape(-1)
-                      for c in contribs]).astype(jnp.float32)
+                      for c in contribs])
 
 
-def phase_kernel(kernel: str, spec: MergeSpec, contribs, ids, base,
-                 oracle) -> dict:
-    """One kernel route through `engine.merge(..., pallas=True)`.
+def _batches(sizes) -> list:
+    """Leaf indices packed largest first into batches of at most the
+    largest leaf's elements, as a flat-batch dispatch holds them; a
+    leaf that fills a batch alone is left out (a flat batch is of many
+    leaves)."""
+    cap = max(sizes)
+    out, cur, n = [], [], 0
+    for i in sorted(range(len(sizes)), key=lambda i: (-sizes[i], i)):
+        if cur and n + sizes[i] > cap:
+            out.append(cur)
+            cur, n = [], 0
+        cur.append(i)
+        n += sizes[i]
+    out.append(cur)
+    return [b for b in out if len(b) > 1]
 
-    The engine fuses same-dtype leaves into groups under its batch cap;
-    a group of one leaf takes the exact eager path instead (covered by
-    the resolve phase). Every fused group must dispatch the kernel — the
-    counter proves there was no silent fallback — and every fused leaf
-    must match the route's `kernels/ref.py` oracle."""
-    cache = engine.EngineCache()
+
+def phase_kernel(kernel: str, merge, oracle, batches) -> dict:
+    """One flat-batch kernel of the library, called through
+    `kernels.ops` on batches of the model's leaves. `merge(batch)`
+    returns the batch's fp32 outputs, one flat row per leaf; each must
+    match `oracle(i)`, the kernel's `kernels/ref.py` oracle on leaf i."""
     with Phase(f"kernel[{kernel}]"):
-        out = ready(engine.merge(contribs, contrib_ids=ids, base=base,
-                                 spec=spec, pallas=True, cache=cache,
-                                 use_cache=False))
-    # the executor's own grouping, to know which leaves the kernel merged
-    plan = engine.plan_for(contribs, contrib_ids=ids, base=base, spec=spec)
-    strat = get_strategy(spec.strategy)
-    groups = engine._dispatch_groups(
-        strat, list(plan.tasks), None,
-        max(t.stacked_nbytes // t.k for t in plan.tasks),
-        fuse=engine._kernel_route(strat, spec.cfg_dict()) is not None)
-    fused = [t for g in groups if len(g) > 1 for t in g]
-    n_groups = sum(1 for g in groups if len(g) > 1)
-    dispatched = cache.obs.counter("kernel_dispatch_total").value(
-        kernel=kernel)
-    if n_groups == 0 or dispatched != n_groups:
-        fail(f"{kernel}: {dispatched} kernel dispatches for {n_groups} "
-             "fused groups")
-    outs = jax.tree_util.tree_leaves(out)
-    bases = jax.tree_util.tree_leaves(base)
-    rows = []
-    for t in fused:
-        got = outs[t.index]
-        want = oracle(t.index, bases[t.index]).reshape(got.shape)
-        rows.append(leaf_diff(got, want.astype(got.dtype)))
-    res = {"dispatches": dispatched, "fused_leaves": len(fused),
-           "exact_leaves": len(plan.tasks) - len(fused),
-           "fused_elements": sum(int(outs[t.index].size) for t in fused),
+        outs = [ready(merge(b)) for b in batches]
+    rows = [leaf_diff(o, oracle(i).reshape(-1))
+            for b, out in zip(batches, outs) for i, o in zip(b, out)]
+    res = {"dispatches": len(batches),
+           "leaves": sum(len(b) for b in batches),
+           "elements": sum(int(o.size) for out in outs for o in out),
            "bitwise": all(r[0] for r in rows),
            "max_abs_diff": max(r[1] for r in rows),
            "within_tol": all(r[1] <= r[2] for r in rows)}
     print(f"kernel[{kernel}]: {json.dumps(res)}", flush=True)
     if not (res["bitwise"] or res["within_tol"]):
-        fail(f"{kernel}: kernel route differs from its oracle beyond "
-             "tolerance")
+        fail(f"{kernel}: kernel differs from its oracle beyond tolerance")
     return res
+
+
+def _split(flat, batch, sizes) -> list:
+    offs = [0]
+    for i in batch:
+        offs.append(offs[-1] + sizes[i])
+    return [flat[lo:hi] for lo, hi in zip(offs, offs[1:])]
 
 
 def phase_kernels(ra, base) -> dict:
     ids = sorted(ra.visible())
     dense = [ra.state.store[i] for i in ids]
+    bases = [b.reshape(-1).astype(jnp.float32)
+             for b in jax.tree_util.tree_leaves(base)]
+    sizes = [int(b.size) for b in bases]
+    batches = _batches(sizes)
+    weights = [1.0 / K] * K
     w = jnp.full((K, 1), 1.0 / K, jnp.float32)
     trim, bins = 0.2, kernel_env.hist_bins
 
-    def nary(i, b):
-        return ref.nary_accum_ref(_stack(dense, i),
-                                  jnp.zeros((1, b.size), jnp.float32), w)
+    def zeros(i):
+        return jnp.zeros((sizes[i],), jnp.float32)
 
-    def ties(i, b):
-        return ref.ties_hist_ref(_stack(dense, i),
-                                 b.reshape(1, -1).astype(jnp.float32),
-                                 trim, bins)
+    def nary(batch):
+        # weight_average: out = sum_i w_i x_i, over a zero base
+        stacked = jnp.concatenate([_rows(dense, i) for i in batch], axis=1)
+        flat = ops.nary_flat_merge(
+            stacked, jnp.zeros((stacked.shape[1],), jnp.float32), weights,
+            preserve_dtype=True)
+        return _split(flat, batch, sizes)
+
+    def nary_ref(i):
+        return ref.nary_accum_ref(_rows(dense, i).astype(jnp.float32),
+                                  zeros(i)[None, :], w)
+
+    def ties(batch):
+        return ops.ties_batch_merge([_rows(dense, i) for i in batch],
+                                    [bases[i] for i in batch], trim)
+
+    def ties_ref(i):
+        return ref.ties_hist_ref(_rows(dense, i).astype(jnp.float32),
+                                 bases[i][None, :], trim, bins)
 
     results = {
-        "nary_accum": phase_kernel(
-            "nary_accum", MergeSpec("weight_average"), dense, ids, base,
-            nary),
-        "ties_hist": phase_kernel(
-            "ties_hist", MergeSpec("ties", {"trim_method": "histogram"}),
-            dense, ids, base, ties),
+        "nary_accum": phase_kernel("nary_accum", nary, nary_ref, batches),
+        "ties_hist": phase_kernel("ties_hist", ties, ties_ref, batches),
     }
     with Phase("quantize contributions (host)"):
         quant = [compress_tree(c) for c in dense]
-    # the ids only memoize planner metadata; the int8 payloads need
-    # their own (sub-roots derive from content digests, not ids)
-    qids = [i + "-int8" for i in ids]
 
-    def qnary(i, b):
-        q = jnp.stack([jnp.asarray(c.leaves[i].q).reshape(-1)
-                       for c in quant])
-        s = jnp.asarray([float(c.leaves[i].scale) for c in quant],
-                        jnp.float32)
-        return ref.quant_nary_ref(q, s, jnp.zeros((1, b.size),
-                                                  jnp.float32), w)
+    def q_rows(i):
+        return jnp.stack([jnp.asarray(c.leaves[i].q).reshape(-1)
+                          for c in quant])
 
-    results["quant_nary"] = phase_kernel(
-        "quant_nary", MergeSpec("weight_average"), quant, qids, base, qnary)
+    def q_scales(i):
+        return jnp.asarray([float(c.leaves[i].scale) for c in quant],
+                           jnp.float32)
+
+    def qnary(batch):
+        return ops.quant_batch_merge([q_rows(i) for i in batch],
+                                     [q_scales(i) for i in batch],
+                                     [zeros(i) for i in batch], weights)
+
+    def qnary_ref(i):
+        return ref.quant_nary_ref(q_rows(i), q_scales(i), zeros(i)[None, :],
+                                  w)
+
+    results["quant_nary"] = phase_kernel("quant_nary", qnary, qnary_ref,
+                                         batches)
     return results
 
 

@@ -11,8 +11,7 @@ set changes. This module splits execution into:
     the Merkle-derived seed where the strategy actually consumes it);
   * an **executor** that runs the plan leaf-by-leaf with bounded live
     memory (at most ~2 leaves' worth of stacked slices at a time),
-    batching same-dtype elementwise leaves into fused dispatches
-    (optionally through the `kernels/nary_accum` Pallas kernel);
+    batching same-dtype elementwise leaves into fused dispatches;
   * a byte-budgeted **per-leaf cache** keyed by sub-root, so an unchanged
     tensor is a cache hit even when the whole-model Merkle root changed.
 
@@ -118,11 +117,10 @@ def _is_qleaf(x: Any) -> bool:
 def _dense_leaf(x: Any, *, obs: Optional[MetricsRegistry]) -> Any:
     """Densify one payload slice if (and only if) it arrived quantized.
 
-    The op sequence is `compression.decompress_tree`'s exactly, so the
-    eager fallback stays byte-identical to densify-then-merge. Counted
-    (`engine_events_total{event=dequant_leaves}`) because the whole
-    point of the merge-on-arrival kernel is that the hot path never
-    calls this — `bench_kernels.py` gates that count at zero."""
+    The op sequence is `compression.decompress_tree`'s exactly, so a
+    merge of quantized contributions is byte-identical to
+    densify-then-merge. Counted under
+    `engine_events_total{event=dequant_leaves}`."""
     if not _is_qleaf(x):
         return x
     if obs is not None:
@@ -171,20 +169,16 @@ class ContribMeta:
     # the planner map a sparse contribution's leaves onto the model's
     # leaves by path rather than by position.
     paths: Tuple[str, ...] = ()
-    # per-leaf int8 dequantization scale for quantized (merge-on-
-    # arrival) contributions, parallel to digests; None = dense fp
-    # payload. Digests always describe the DEQUANTIZED tensor — content
-    # identity is defined on wire-format values (compression.py), so a
-    # quantized and a densified copy of the same contribution share
-    # cache keys.
+    # per-leaf int8 dequantization scale for quantized contributions,
+    # parallel to digests; None = dense fp payload. Digests always
+    # describe the DEQUANTIZED tensor — content identity is defined on
+    # wire-format values (compression.py), so a quantized and a
+    # densified copy of the same contribution share cache keys.
     scales: Optional[Tuple[Optional[float], ...]] = None
 
     @property
     def leaf_count(self) -> int:
         return len(self.digests)
-
-    def scale_of(self, local: int) -> Optional[float]:
-        return self.scales[local] if self.scales is not None else None
 
 
 _META_MEMO: "OrderedDict[str, ContribMeta]" = OrderedDict()
@@ -230,9 +224,8 @@ def contrib_meta(contribution: Any, *, eid: Optional[str] = None,
     Quantized contributions (`CompressedTree`) are planned in place:
     leaves flatten to `CompressedLeaf` payloads, digests are computed
     on a transient per-leaf dequantization (one leaf live at a time —
-    never the k x P densified copy), and the per-leaf scales ride into
-    the meta so the plan can account int8 wire bytes and the executor
-    can route the batch through the merge-on-arrival kernel.
+    never the k x P densified copy), and the meta records the per-leaf
+    scales.
 
     A miss (not a memo hit) is spanned as `engine.contrib_meta`, its
     hashed bytes counted under `hash_bytes_total{site=contrib_meta}`
@@ -301,11 +294,10 @@ def note_meta(eid: str, paths: Sequence[str], digests: Sequence[bytes],
     execute — before (or without) fetching a single chunk. treedef stays
     None: such metas are mapped onto the model by path.
 
-    `scales` threads the int8 dequantization scale announced per leaf
-    ref (zero-point is identically 0 — the wire codec is symmetric)
-    into the plan: the planner accounts the leaf's stacked bytes at the
-    int8 wire width and the executor knows the payload will arrive as a
-    `CompressedLeaf` it can merge on arrival."""
+    `scales` records the int8 dequantization scale announced per leaf
+    ref (zero-point is identically 0 — the wire codec is symmetric):
+    such a payload arrives as a `CompressedLeaf`, which the executor
+    densifies slice by slice."""
     meta = ContribMeta(
         treedef=None,
         digests=tuple(digests),
@@ -352,21 +344,10 @@ class LeafTask:
     contributors: Tuple[int, ...] = ()
     digests: Tuple[bytes, ...] = ()
     base_frag: bytes = b""
-    # per-contributor int8 dequant scale (None entry = dense fp payload),
-    # parallel to `contributors`; None = no contributor is quantized.
-    # Threaded from wire announcements (note_meta) or resident
-    # CompressedTrees so the executor can pick the merge-on-arrival
-    # kernel and the planner can account wire-width stacked bytes.
-    scales: Optional[Tuple[Optional[float], ...]] = None
 
     @property
     def k(self) -> int:
         return len(self.contributors)
-
-    @property
-    def quantized(self) -> bool:
-        return self.scales is not None and \
-            all(s is not None for s in self.scales)
 
 
 @dataclass(frozen=True)
@@ -481,14 +462,11 @@ def plan_merge(metas: Sequence[ContribMeta],
         path_index = {p: i for i, p in enumerate(paths)}
         contributors: List[List[int]] = [[] for _ in range(n_leaves)]
         leaf_digests: List[List[bytes]] = [[] for _ in range(n_leaves)]
-        leaf_scales: List[List[Optional[float]]] = [[] for _ in
-                                                    range(n_leaves)]
         for j, (m, cov) in enumerate(zip(metas, coverages)):
             if cov is None and m.treedef is not None:
                 for i in range(n_leaves):
                     contributors[i].append(j)
                     leaf_digests[i].append(m.digests[i])
-                    leaf_scales[i].append(m.scale_of(i))
                 continue
             # path-mapped: sparse, or dense-by-manifest (treedef unknown)
             if cov is not None and set(m.paths) != set(cov):
@@ -508,7 +486,6 @@ def plan_merge(metas: Sequence[ContribMeta],
                         "disagrees with the model structure")
                 contributors[i].append(j)
                 leaf_digests[i].append(m.digests[local])
-                leaf_scales[i].append(m.scale_of(local))
         if base is None:
             base_frags: Sequence[bytes] = [_NO_BASE] * n_leaves
         else:
@@ -533,15 +510,10 @@ def plan_merge(metas: Sequence[ContribMeta],
                 base_only.append(i)
                 continue
             digs = tuple(leaf_digests[i])
-            numel = 1
-            for d in shapes[i]:
-                numel *= d
-            itemsize = jnp.dtype(dtypes[i]).itemsize
-            # quantized contributors stack at int8 wire width (the
-            # merge-on-arrival kernel never densifies them)
-            stacked = sum(numel * (1 if s is not None else itemsize)
-                          for s in leaf_scales[i])
-            scls = tuple(leaf_scales[i])
+            # every slice is stacked dense, a quantized one included
+            # (`_dense_leaf`): k_i slices at the leaf's own width
+            stacked = ki * math.prod(shapes[i]) * \
+                jnp.dtype(dtypes[i]).itemsize
             tasks.append(
                 LeafTask(index=i, path=paths[i],
                          sub_root=_leaf_subroot(frag, base_frags[i], digs,
@@ -549,9 +521,7 @@ def plan_merge(metas: Sequence[ContribMeta],
                          shape=shapes[i], dtype=dtypes[i],
                          stacked_nbytes=stacked,
                          contributors=tuple(contributors[i]),
-                         digests=digs, base_frag=base_frags[i],
-                         scales=scls if any(s is not None for s in scls)
-                         else None))
+                         digests=digs, base_frag=base_frags[i]))
     any_sparse = any(c is not None for c in coverages)
     return MergePlan(strategy=spec.strategy, reduction=spec.reduction,
                      seed=seed, k=k, cfg=spec.cfg,
@@ -832,7 +802,6 @@ def reset_exec_stats(cache: Optional[EngineCache] = None) -> None:
 def execute_plan(plan: MergePlan, contribs: Optional[Sequence[Any]], *,
                  base: Any = None, use_cache: bool = True,
                  max_batch_bytes: Optional[int] = None,
-                 pallas: bool = False,
                  cache: Optional[EngineCache] = None) -> Any:
     """Run a merge plan and return the merged pytree.
 
@@ -850,14 +819,6 @@ def execute_plan(plan: MergePlan, contribs: Optional[Sequence[Any]], *,
     blocks: `_chain_blocks`): on an asynchronous device (a TPU) the host
     would otherwise enqueue many dispatches ahead, and all their
     transients would be live at once.
-
-    `pallas=True` routes linear-family batches through the fused
-    `kernels/nary_accum` Pallas kernel (fp32 accumulation; validated to
-    tolerance, not byte-identical — leave off where Def. 6 transparency
-    against the legacy path is required). Pallas-produced leaves are
-    NEVER written to the sub-root cache: the cache serves the
-    byte-exact path, and an approximate entry would silently poison a
-    later exact resolve.
     """
     cache = _cache_or_default(cache)
     strat = get_strategy(plan.strategy)
@@ -903,19 +864,17 @@ def execute_plan(plan: MergePlan, contribs: Optional[Sequence[Any]], *,
                                  f"got {len(contribs)}")
             flat = _flatten_contribs(plan, contribs)
 
-            def leaf_raw(j: int, t: LeafTask):
+            def leaf_of(j: int, t: LeafTask):
+                # a quantized slice is densified on access, one at a
+                # time (exact decompress_tree math, counted)
                 f = flat[j]
                 if f is None:
                     raise KeyError(
                         f"contribution {j} is needed by leaf {t.path!r} "
                         "but its payload was not supplied")
-                return f[t.index] if isinstance(f, list) else f[t.path]
-
-            def leaf_of(j: int, t: LeafTask):
-                # eager paths densify quantized slices on access (exact
-                # decompress_tree math, counted); the kernel route reads
-                # the raw int8 payload via leaf_raw instead
-                return _dense_leaf(leaf_raw(j, t), obs=cache.obs)
+                return _dense_leaf(
+                    f[t.index] if isinstance(f, list) else f[t.path],
+                    obs=cache.obs)
 
             cfg = plan.cfg_dict()
             for t, m, aux in resumes:
@@ -941,12 +900,8 @@ def execute_plan(plan: MergePlan, contribs: Optional[Sequence[Any]], *,
             if misses:
                 leaf_bytes = max(t.stacked_nbytes // t.k
                                  for t in plan.tasks)
-                kernel_fuse = pallas and \
-                    _kernel_route(strat, cfg) is not None
                 for group in _dispatch_groups(strat, misses,
-                                              max_batch_bytes, leaf_bytes,
-                                              fuse=kernel_fuse):
-                    approximate = False
+                                              max_batch_bytes, leaf_bytes):
                     if len(group) == 1:
                         with record_quantile_paths() as taken:
                             o, a = _execute_leaf(strat, plan, group[0],
@@ -958,9 +913,9 @@ def execute_plan(plan: MergePlan, contribs: Optional[Sequence[Any]], *,
                                     path=path)
                         out, auxs = [o], [a]
                     else:
-                        out, auxs, approximate = _execute_batch(
+                        out, auxs = _execute_batch(
                             strat, plan, group, leaf_of, base_leaves,
-                            cache, pallas=pallas, leaf_raw=leaf_raw)
+                            cache)
                         cache.stats["batched_leaves"] += len(group)
                     with span("engine.wait", leaves=len(group)):
                         jax.block_until_ready(out)
@@ -968,7 +923,7 @@ def execute_plan(plan: MergePlan, contribs: Optional[Sequence[Any]], *,
                     cache.stats["leaf_tasks"] += len(group)
                     for t, o, a in zip(group, out, auxs):
                         outputs[t.index] = o
-                        if use_cache and not approximate:
+                        if use_cache:
                             nb = int(o.nbytes) + (int(a.nbytes)
                                                   if a is not None else 0)
                             cache.put(t.sub_root, o, nb, aux=a)
@@ -980,9 +935,9 @@ def _flatten_contribs(plan: MergePlan, contribs: Sequence[Any]
     """Per-contribution leaf accessors: a flatten-order list for dense
     contributions, a path-keyed dict for sparse ones, None for payloads
     the executor was told it will not need. Quantized contributions
-    (`CompressedTree`) flatten to their `CompressedLeaf` payloads —
-    densification is deferred to the access site so the kernel route
-    can consume the int8 bytes directly."""
+    (`CompressedTree`) flatten to their `CompressedLeaf` payloads:
+    each is densified where it is read, so only the slices of the leaf
+    or batch in hand are ever dense, never the whole contribution."""
     covs = plan.coverages or (None,) * plan.k
     out: List[Any] = []
     for c, cov in zip(contribs, covs):
@@ -1040,21 +995,15 @@ def plan_needed_ids(plan: MergePlan,
 
 
 def _dispatch_groups(strat: Strategy, misses: List[LeafTask],
-                     max_batch_bytes: Optional[int], leaf_bytes: int, *,
-                     fuse: bool = False) -> List[List[LeafTask]]:
+                     max_batch_bytes: Optional[int], leaf_bytes: int
+                     ) -> List[List[LeafTask]]:
     """Partition missed tasks into dispatches. Elementwise strategies
     fuse same-dtype leaves (flattened + concatenated) up to the batch
     byte cap (by default `leaf_bytes`, the plan's largest leaf, times
     the batch's k); everything else runs one leaf per dispatch. Under
     sparse contributions only leaves with the SAME ordered contributor
-    subset fuse — a [k_i, N] batch has one k_i.
-
-    `fuse=True` forces fusing for strategies that are not elementwise-
-    batchable but have a kernel-frontier flat-batch route (histogram
-    TIES, counter-RNG DARE): those kernels keep per-leaf block
-    boundaries, so per-leaf global statistics (trim thresholds, RNG
-    offsets) survive batching."""
-    if not (strat.batchable or fuse):
+    subset fuse — a [k_i, N] batch has one k_i."""
+    if not strat.batchable:
         return [[t] for t in misses]
     groups: List[List[LeafTask]] = []
     by_dtype: Dict[Any, List[LeafTask]] = {}
@@ -1294,107 +1243,9 @@ def _leaf_tree_fold(strat, slices, base_leaves, i, seed, cfg):
     return level[0]
 
 
-def _kernel_route(strat: Strategy, cfg: Dict[str, Any]) -> Optional[str]:
-    """Which kernel-frontier flat-batch route (beyond the elementwise
-    nary one) this strategy + cfg rides, or None.
-
-    - "ties_hist": TIES with the histogram trim — the sort-free
-      threshold makes the whole pipeline batchable (3 launches/batch).
-    - "dare": DARE through the counter-based kernel RNG. Opt-in via
-      `kernel_env.dare_kernel_rng`: the sampler differs from the exact
-      path's `jax.random`, so it is deterministic and replica-
-      convergent only when every replica opts in.
-    """
-    from repro.kernels.config import kernel_env
-    if strat.name == "ties" and \
-            str(cfg.get("trim_method", "quantile")) == "histogram":
-        return "ties_hist"
-    if strat.name == "dare" and kernel_env.dare_kernel_rng:
-        return "dare"
-    return None
-
-
-def _kernel_batch(strat: Strategy, plan: MergePlan, group: List[LeafTask],
-                  leaf_raw, base_leaves, cache: EngineCache
-                  ) -> Optional[Tuple[List[Any], List[Any], bool]]:
-    """Kernel-frontier dispatch: one (or three, for histogram TIES)
-    Pallas launches for a whole group of same-dtype leaves, keeping
-    per-leaf block boundaries so per-leaf statistics survive batching.
-
-    Routes, in priority order: histogram-trim TIES; counter-RNG DARE
-    (opt-in); int8 merge-on-arrival for linear-family groups whose
-    every slice arrived quantized (dequantizes inside the tile — the
-    fp32 densified batch never exists in HBM). Returns None when no
-    route applies (caller falls back to the generic batch), else
-    (outs, auxs, True): kernel outputs are fp32-accumulated tolerance
-    outputs and are NEVER written to the byte-exact cache."""
-    cfg = plan.cfg_dict()
-    contributors = group[0].contributors
-    ki = len(contributors)
-    if not jnp.issubdtype(jnp.dtype(group[0].dtype), jnp.floating):
-        return None
-    route = _kernel_route(strat, cfg)
-    from repro.kernels import ops as kops
-    from repro.kernels.config import kernel_env
-
-    def dense_rows(t: LeafTask):
-        return jnp.stack([
-            _dense_leaf(leaf_raw(j, t), obs=cache.obs).reshape(-1)
-            for j in contributors])
-
-    def base_row(t: LeafTask, zeros: bool = False):
-        if zeros or base_leaves is None:
-            n = 1
-            for d in t.shape:
-                n *= d
-            return jnp.zeros((n,), jnp.float32)
-        return jnp.asarray(base_leaves[t.index]).reshape(-1).astype(
-            jnp.float32)
-
-    if route == "ties_hist":
-        leaves = [dense_rows(t) for t in group]
-        bases = [base_row(t) for t in group]
-        cache.note_stacked(2 * sum(int(l.nbytes) for l in leaves))
-        flats = kops.ties_batch_merge(
-            leaves, bases, float(cfg.get("trim", 0.2)))
-        kernel = "ties_hist"
-    elif route == "dare":
-        leaves = [dense_rows(t) for t in group]
-        bases = [base_row(t) for t in group]
-        cache.note_stacked(2 * sum(int(l.nbytes) for l in leaves))
-        flats = kops.dare_batch_merge(
-            leaves, bases, [plan.seed + t.index for t in group],
-            float(cfg.get("p", 0.5)))
-        kernel = "dare"
-    else:
-        # int8 merge-on-arrival: linear-family group, all slices int8
-        form = _nary_weights(strat.name, ki, cfg)
-        if form is None or not kernel_env.quantized:
-            return None
-        raw = [[leaf_raw(j, t) for j in contributors] for t in group]
-        if not all(_is_qleaf(x) for slices in raw for x in slices):
-            return None
-        weights, uses_base = form
-        q_leaves = [jnp.stack([jnp.asarray(x.q).reshape(-1)
-                               for x in slices]) for slices in raw]
-        scales = [jnp.asarray([float(x.scale) for x in slices],
-                              jnp.float32) for slices in raw]
-        bases = [base_row(t, zeros=not uses_base) for t in group]
-        cache.note_stacked(2 * sum(int(q.nbytes) for q in q_leaves))
-        flats = kops.quant_batch_merge(q_leaves, scales, bases, weights)
-        kernel = "quant_nary"
-        cache.obs.counter("engine_quant_leaves_merged_total").inc(len(group))
-    cache.stats["pallas_dispatches"] += 1
-    cache.obs.counter("kernel_dispatch_total").inc(kernel=kernel)
-    dt = jnp.dtype(group[0].dtype)
-    outs = [f.reshape(t.shape).astype(dt) for f, t in zip(flats, group)]
-    return outs, [None] * len(group), True
-
-
 def _execute_batch(strat: Strategy, plan: MergePlan, group: List[LeafTask],
-                   leaf_of, base_leaves, cache: EngineCache, *,
-                   pallas: bool, leaf_raw=None
-                   ) -> Tuple[List[Any], List[Any], bool]:
+                   leaf_of, base_leaves, cache: EngineCache
+                   ) -> Tuple[List[Any], List[Any]]:
     """Fused dispatch over same-dtype, same-contributor-subset
     elementwise leaves: flatten each leaf's k_i slices, concatenate
     along the element axis into one row per contribution, apply the
@@ -1403,20 +1254,13 @@ def _execute_batch(strat: Strategy, plan: MergePlan, group: List[LeafTask],
     `_chain_blocks`), slice the outputs back. Elementwise leaf
     functions reduce only over the k axis, so per-element arithmetic —
     and therefore output bytes — is identical to leaf-at-a-time
-    execution. Returns (outputs, auxs,
-    approximate): auxs are per-leaf fold accumulator slices for
-    incremental strategies (sliced from the batch accumulator —
-    elementwise, so bitwise equal to per-leaf folds); approximate=True
-    means a fused Pallas route produced the outputs (fp32-accumulated,
-    tolerance only) and the caller must not cache them."""
+    execution. Returns (outputs, auxs): auxs are per-leaf fold
+    accumulator slices for incremental strategies (sliced from the
+    batch accumulator — elementwise, so bitwise equal to per-leaf
+    folds)."""
     contributors = group[0].contributors
     ki = len(contributors)
     cfg = plan.cfg_dict()
-    if pallas and leaf_raw is not None:
-        routed = _kernel_batch(strat, plan, group, leaf_raw, base_leaves,
-                               cache)
-        if routed is not None:
-            return routed
     # each contribution's leaves, flattened and joined: a row of the
     # batch, of a shape that does not depend on k
     rows = [jnp.concatenate([leaf_of(j, t).reshape(-1) for t in group])
@@ -1431,28 +1275,21 @@ def _execute_batch(strat: Strategy, plan: MergePlan, group: List[LeafTask],
     else:
         b = jnp.concatenate([jnp.asarray(base_leaves[t.index]).reshape(-1)
                              for t in group])
-    approximate = False
-    merged = None
     acc = None
-    if pallas:
-        merged = _nary_pallas_batch(strat, jnp.stack(rows), b, ki, cfg,
-                                    cache)
-        approximate = merged is not None
-    if merged is None:
-        if strat.fold is not None and ki >= strat.fold.min_k:
-            kw = dict(strat.defaults)
-            kw.update(cfg)
-            # the rows are built, so they count in what is in use; the
-            # fold, waited for step by step, holds buffers of one row
-            planned = _chain_blocks((n,), stack // ki, rows[0])
-            merged, acc = _in_blocks(
-                cache, plan.strategy, group[0].path, len(group), planned,
-                lambda blocks: _fold_blocks(strat.fold, rows, b, blocks,
-                                            **kw))
-        else:
-            merged = strat.apply_leaf(jnp.stack(rows), b,
-                                      leaf_index=group[0].index,
-                                      seed=plan.seed, **cfg)
+    if strat.fold is not None and ki >= strat.fold.min_k:
+        kw = dict(strat.defaults)
+        kw.update(cfg)
+        # the rows are built, so they count in what is in use; the
+        # fold, waited for step by step, holds buffers of one row
+        planned = _chain_blocks((n,), stack // ki, rows[0])
+        merged, acc = _in_blocks(
+            cache, plan.strategy, group[0].path, len(group), planned,
+            lambda blocks: _fold_blocks(strat.fold, rows, b, blocks,
+                                        **kw))
+    else:
+        merged = strat.apply_leaf(jnp.stack(rows), b,
+                                  leaf_index=group[0].index,
+                                  seed=plan.seed, **cfg)
     outs: List[Any] = []
     auxs: List[Any] = []
     off = 0
@@ -1462,46 +1299,7 @@ def _execute_batch(strat: Strategy, plan: MergePlan, group: List[LeafTask],
         auxs.append(acc[off:off + size].reshape(t.shape)
                     if acc is not None else None)
         off += size
-    return outs, auxs, approximate
-
-
-def _nary_weights(name: str, k: int, cfg: Dict[str, Any]
-                  ) -> Optional[Tuple[List[float], bool]]:
-    """(weights, uses_base) for strategies of the nary_accum form
-    out = base + sum_i w_i (x_i - base); None if not of that form."""
-    if name == "weight_average":
-        return [1.0 / k] * k, False
-    if name == "linear":
-        t = float(cfg.get("t", 0.5))
-        if k == 2:
-            return [1.0 - t, t], False
-        return [1.0 / k] * k, False
-    if name == "task_arithmetic":
-        return [float(cfg.get("lam", 1.0))] * k, True
-    if name == "negative_merge":
-        return [-float(cfg.get("lam", 0.5)) / k] * k, True
-    return None
-
-
-def _nary_pallas_batch(strat: Strategy, stacked, b, k: int,
-                       cfg: Dict[str, Any], cache: EngineCache):
-    """Fused Pallas nary_accum dispatch for the linear family; returns
-    None when the strategy has no nary weight form (caller falls back to
-    the byte-exact jnp path)."""
-    form = _nary_weights(strat.name, k, cfg)
-    if form is None:
-        return None
-    weights, uses_base = form
-    from repro.kernels.ops import nary_flat_merge
-    base_flat = b if uses_base else jnp.zeros_like(b)
-    # sub-fp32 batches stream in their own dtype and upcast in-tile
-    preserve = stacked.dtype != jnp.float32 and \
-        jnp.issubdtype(stacked.dtype, jnp.floating)
-    out = nary_flat_merge(stacked, base_flat, weights,
-                          preserve_dtype=preserve)
-    cache.stats["pallas_dispatches"] += 1
-    cache.obs.counter("kernel_dispatch_total").inc(kernel="nary_accum")
-    return out.astype(stacked.dtype)
+    return outs, auxs
 
 
 # ---------------------------------------------------------------------------
@@ -1561,7 +1359,7 @@ def merge(contribs: Sequence[Any], strategy_name: Optional[str] = None, *,
           contrib_ids: Optional[Sequence[str]] = None, base: Any = None,
           seed: int = 0, reduction: Optional[str] = None,
           use_cache: bool = True,
-          max_batch_bytes: Optional[int] = None, pallas: bool = False,
+          max_batch_bytes: Optional[int] = None,
           spec: Optional[MergeSpec] = None,
           cache: Optional[EngineCache] = None,
           coverages: Optional[Sequence[Optional[Tuple[str, ...]]]]
@@ -1614,8 +1412,7 @@ def merge(contribs: Sequence[Any], strategy_name: Optional[str] = None, *,
                     base=base, seed=seed, spec=spec,
                     coverages=coverages, obs=cache.obs)
     return execute_plan(plan, contribs, base=base, use_cache=use_cache,
-                        max_batch_bytes=max_batch_bytes, pallas=pallas,
-                        cache=cache)
+                        max_batch_bytes=max_batch_bytes, cache=cache)
 
 
 def _is_hex(s: str) -> bool:

@@ -18,12 +18,6 @@ REPRO_KERNEL_INTERPRET      "1"/"true" forces Pallas interpret mode, "0"/
 REPRO_KERNEL_BLOCK          per-grid-step tile width (default 2048).
 REPRO_KERNEL_HIST_BINS      histogram trim-quantile resolution (default
                             512, matching `strategies.catalog`).
-REPRO_KERNEL_QUANTIZED      "0" disables the int8 merge-on-arrival path
-                            (engine falls back to dequantize-then-merge).
-REPRO_KERNEL_DARE_RNG       "1" lets the engine's batched executor route
-                            DARE through the counter-based kernel RNG
-                            (off by default: the catalog's exact path
-                            uses `jax.random`, a different sampler).
 ==========================  ================================================
 """
 from __future__ import annotations
@@ -65,10 +59,6 @@ class KernelEnv:
         self.block: int = int(os.environ.get("REPRO_KERNEL_BLOCK", "2048"))
         self.hist_bins: int = int(
             os.environ.get("REPRO_KERNEL_HIST_BINS", "512"))
-        quant = _env_flag("REPRO_KERNEL_QUANTIZED")
-        self.quantized: bool = True if quant is None else quant
-        dare = _env_flag("REPRO_KERNEL_DARE_RNG")
-        self.dare_kernel_rng: bool = False if dare is None else dare
         if self.block <= 0:
             raise ValueError(f"REPRO_KERNEL_BLOCK must be > 0, "
                              f"got {self.block}")

@@ -6,14 +6,15 @@ the k x p mask never exists in HBM (vs. the eager pipeline which
 materializes the random tensor, the mask, and the rescaled taus). One
 streaming pass: read (k, BLOCK) + base tile, write merged tile.
 
-The kernel is layout-driven so the per-leaf path and the engine's flat-
-batch dispatch share one body: each grid step looks up its leaf in the
-scalar-prefetched `leaf_id` table (SMEM), reads that leaf's (seed, padded
-length, first block) row, and reconstructs the same `row * npad + col`
-global index the per-leaf launch would have used. Because the hash is
-exact uint32 arithmetic, flat-batch output is byte-identical to per-leaf
-dispatch by construction — a batch block at offset `start` inside its
-leaf draws exactly the mask the standalone launch drew at that offset.
+The kernel is layout-driven so the per-leaf path and the flat-batch
+dispatch (`ops.dare_batch_merge`) share one body: each grid step looks
+up its leaf in the scalar-prefetched `leaf_id` table (SMEM), reads that
+leaf's (seed, padded length, first block) row, and reconstructs the
+same `row * npad + col` global index the per-leaf launch would have
+used. Because the hash is exact uint32 arithmetic, flat-batch output is
+byte-identical to per-leaf dispatch by construction — a batch block at
+offset `start` inside its leaf draws exactly the mask the standalone
+launch drew at that offset.
 """
 from __future__ import annotations
 

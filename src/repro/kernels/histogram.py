@@ -4,7 +4,7 @@ The exact trim threshold (`ops.ties_merge(trim_method="quantile")`,
 `strategies.select.exact_quantile_rows`) is a per-row order statistic
 over all of a leaf's k x p elements: every leaf needs its own selection
 before the fused merge kernel can launch, so the exact trim never joins
-the engine's one-launch-per-batch flat dispatch. This module uses the
+a one-launch-per-batch flat dispatch. This module uses the
 catalog's histogram trim instead (the threshold math of
 `strategies.catalog._ties_nd_histogram`, bit-for-bit), an approximate
 threshold and so a different result:

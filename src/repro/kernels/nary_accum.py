@@ -7,10 +7,10 @@ weight averaging (w=1/k, base=0), linear interpolation, task arithmetic
 (w=lambda), negative merge (w=-lambda/k), DAM / AdaMerging (per-
 contribution scalar weights computed outside from norms/variances).
 
-The merge engine's batched executor (`core/engine`) concatenates many
-same-dtype leaves into a single [k, N] flat batch and dispatches it
-here once via `ops.nary_flat_merge` — one kernel launch per batch
-instead of one per tensor.
+`ops.nary_flat_merge` takes many same-dtype leaves concatenated into a
+single [k, N] flat batch and dispatches it here once — one kernel
+launch per batch instead of one per tensor. The merge engine does not
+call it: its fused batch is the exact eager fold.
 """
 from __future__ import annotations
 

@@ -8,11 +8,13 @@ Defaults come from `kernels.config.kernel_env` — block size, interpret
 mode (backend probed once, `REPRO_KERNEL_INTERPRET` overrides), and
 histogram bins — instead of per-call backend probing.
 
-The `*_batch_merge` entry points are the merge engine's kernel-frontier
-dispatch: many same-dtype leaves, each zero-padded to a block multiple
-and concatenated into one [k, N] flat batch so every (k, BLOCK) tile
+The `*_batch_merge` entry points are the flat-batch dispatch: many
+same-dtype leaves, each zero-padded to a block multiple and
+concatenated into one [k, N] flat batch so every (k, BLOCK) tile
 belongs to exactly one leaf, merged in one kernel launch (3 launches
-for histogram TIES) per batch instead of one per tensor.
+for histogram TIES) per batch instead of one per tensor. The merge
+engine (`core/engine`) calls none of them: they are a library, checked
+against `kernels/ref.py`.
 """
 from __future__ import annotations
 
@@ -129,8 +131,9 @@ def dare_batch_merge(leaves: Sequence[jax.Array],
     """Flat-batch DARE: one launch for many leaves, byte-identical to
     per-leaf `dare_pallas` with the same per-leaf seed.
 
-    `seeds[j]` is leaf j's uint32 RNG seed (the engine threads the
-    plan's global leaf index into it so replicas agree).
+    `seeds[j]` is leaf j's uint32 RNG seed (a caller that needs
+    replicas to agree derives it from the merge seed and the global
+    leaf index).
     """
     block, interpret = _defaults(block, interpret)
     stacked, base, lengths, leaf_id, first, offsets = _flat_batch(
@@ -178,7 +181,7 @@ def ties_merge(contribs, base=None, trim: float = 0.2, *,
                interpret: Optional[bool] = None):
     """Fused TIES. `trim_method="histogram"` (default) resolves the trim
     threshold with the sort-free two-pass histogram kernel — the same
-    path the engine's flat-batch dispatch uses; `"quantile"` keeps the
+    path `ties_batch_merge` uses; `"quantile"` keeps the
     exact threshold, `jnp.quantile`'s bit for bit (one counting
     selection per leaf, `strategies.select`; a per-row statistic, so
     it blocks batching)."""
@@ -232,7 +235,7 @@ def nary_flat_merge(stacked_flat, base_flat, weights, *,
     """One fused nary_accum dispatch over an already-flattened batch.
 
     `stacked_flat`: [k, N] — many same-dtype leaves' slices concatenated
-    along the element axis (the merge engine's batched dispatch);
+    along the element axis (a flat batch);
     `base_flat`: [N]; `weights`: [k] scalars. Returns fp32 [N]
     (out = base + sum_i w_i (x_i - base)), one HBM pass for the whole
     batch instead of one kernel launch per leaf.

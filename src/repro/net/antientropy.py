@@ -218,11 +218,11 @@ class SyncNode:
         self.storage = None
         self._state = state or CRDTMergeState()
         self.compress_blobs = compress_blobs
-        # merge-on-arrival opt-in: keep arriving int8 payloads
-        # (CompressedTree) in the store un-densified — the merge engine
-        # plans against their announced/derived metadata and merges the
-        # int8 bytes directly in the quantized Pallas kernel. Off by
-        # default: the legacy store holds dequantized tensors.
+        # opt-in: keep arriving int8 payloads (CompressedTree) in the
+        # store un-densified — the merge engine plans against their
+        # announced/derived metadata and densifies one int8 slice at a
+        # time where it reads it. Off by default: the legacy store
+        # holds dequantized tensors.
         self.keep_quantized = keep_quantized
         self.max_frame_bytes = max_frame_bytes
         self.chunk_window = max(1, chunk_window)
@@ -762,7 +762,7 @@ class SyncNode:
                     # payload, i.e. what the receiver's store will hold;
                     # leaf_refs dequantizes CompressedTree leaves one at
                     # a time for digesting and carries each int8 leaf's
-                    # scale so the planner can merge-on-arrival.
+                    # scale into the planner's metadata.
                     sparse_entries.append(
                         SparseManifestEntry(me, leaf_refs(payload)))
                     self.stats["sparse_manifests_sent"] += 1

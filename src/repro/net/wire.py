@@ -315,9 +315,8 @@ class LeafRef:
     is identically 0 by construction (the codec is symmetric), so the
     scale alone fully determines dequantization. The digest still
     describes the DEQUANTIZED tensor — content identity is defined on
-    wire-format values — which is what lets a receiver plan (and the
-    merge-on-arrival kernel execute) against the int8 bytes without
-    ever densifying."""
+    wire-format values — which is what lets a receiver plan against the
+    int8 bytes without densifying them."""
     path: str
     digest: bytes                  # 32B tensor_digest
     dtype: str
@@ -1157,8 +1156,8 @@ def msg_to_state(msg: StateMsg, *,
     # Compressed blobs decompress on arrival by default: the store then
     # holds the dequantized wire-format tensors (content identity,
     # Assumption 11). `keep_quantized=True` (SyncNode opt-in) stores the
-    # CompressedTree as-is — the merge engine plans and merges directly
-    # from the int8 payloads (merge-on-arrival), and content identity is
+    # CompressedTree as-is — the merge engine plans from the int8
+    # payloads and densifies each slice it reads, and content identity is
     # unchanged because digests are always computed on dequantized
     # values.
     if keep_quantized:

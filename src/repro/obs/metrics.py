@@ -125,16 +125,6 @@ declare("engine_sparse_leaves_skipped", "gauge",
         "Leaves of the most recent plan not touched by every "
         "contribution: partial-subset tasks plus inherit-base leaves",
         deterministic=True)
-declare("kernel_dispatch_total", "counter",
-        "Kernel-frontier flat-batch Pallas dispatches by kernel "
-        "(nary_accum, ties_hist, dare, quant_nary) — the catalogued "
-        "successor to the ad-hoc engine_events_total{event="
-        "pallas_dispatches} stat, which stays as the all-kernel sum",
-        labels=("kernel",), deterministic=True)
-declare("engine_quant_leaves_merged_total", "counter",
-        "Leaves merged directly from int8 wire payloads by the "
-        "merge-on-arrival kernel (dequantized in-tile; zero fp32 "
-        "dequantize round-trips through HBM)", deterministic=True)
 declare("resolve_fold_updates_total", "counter",
         "Contributions folded into cached accumulators by prefix-fold "
         "resumption (per EngineCache)", deterministic=True)

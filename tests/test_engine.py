@@ -1,6 +1,6 @@
 """Planner/executor merge engine: byte-for-byte legacy equivalence for
 all 26 strategies, per-leaf incremental re-merge, ordering convergence,
-byte-budgeted caching, leaf-granular fetch, and the batched Pallas path."""
+byte-budgeted caching, leaf-granular fetch, and the fused batch path."""
 import hashlib
 
 import jax
@@ -12,6 +12,7 @@ from conftest import make_contribs
 
 from repro.api import MergeSpec
 from repro.core import engine
+from repro.core.compression import compress_tree, decompress_tree
 from repro.core.properties import controlled_tensors
 from repro.core.resolve import (
     cache_info, canonical_order, clear_cache, hierarchical_resolve,
@@ -420,39 +421,64 @@ def test_hierarchical_resolve_honors_fetch_and_reduction():
     clear_cache()
 
 
-def test_pallas_batched_dispatch_matches_to_tolerance():
-    """The fused nary_accum Pallas route (interpret mode on CPU) agrees
-    with the byte-exact jnp path to fp32 tolerance for the linear
-    family, and actually dispatches through the kernel."""
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("name,kw", [
+    ("weight_average", {}), ("linear", {"t": 0.3}),
+    ("task_arithmetic", {"lam": 0.7}), ("negative_merge", {})])
+def test_fused_linear_batch_exact_and_cached(name, kw, dtype):
+    """The linear family fuses a pytree's same-dtype leaves into one
+    batch whose output is bitwise the reference's, and every output of
+    it is cached: a second merge on the same cache dispatches nothing."""
     contribs, base = _pytree_contribs(k=4, seed=13)
-    for name, kw in (("weight_average", {}), ("linear", {"t": 0.3}),
-                     ("task_arithmetic", {"lam": 0.7}),
-                     ("negative_merge", {})):
-        engine.reset_exec_stats()
-        ref = engine.merge(contribs, name, base=base, use_cache=False, **kw)
-        got = engine.merge(contribs, name, base=base, use_cache=False,
-                           pallas=True, **kw)
-        assert engine.exec_stats()["pallas_dispatches"] > 0, name
-        for r, g in zip(jax.tree_util.tree_leaves(ref),
-                        jax.tree_util.tree_leaves(got)):
-            assert np.allclose(np.asarray(r), np.asarray(g),
-                               atol=1e-5), name
+    contribs, base = jax.tree_util.tree_map(
+        lambda x: x.astype(dtype), (contribs, base))
+    cache = engine.EngineCache()
+    got = engine.merge(contribs, name, base=base, cache=cache, **kw)
+    stats = engine.exec_stats(cache)
+    assert stats["batched_leaves"] > 0, name
+    assert _bytes_equal(got, reference_apply(name, contribs, base=base,
+                                             **kw)), name
+    n_leaves = len(jax.tree_util.tree_leaves(base))
+    again = engine.merge(contribs, name, base=base, cache=cache, **kw)
+    after = engine.exec_stats(cache)
+    assert after["hits"] - stats.get("hits", 0) == n_leaves
+    assert after["dispatches"] == stats["dispatches"]
+    assert _bytes_equal(again, got)
 
 
-def test_pallas_outputs_never_poison_the_exact_cache():
-    """Regression: a pallas=True merge with caching enabled must NOT
-    leave its approximate (fp32-accumulated) leaves in the sub-root
-    cache — a later exact merge would silently return non-legacy
-    bytes."""
-    clear_cache()
-    contribs, base = _pytree_contribs(k=4, seed=17)
-    engine.merge(contribs, "task_arithmetic", base=base, lam=0.7,
-                 pallas=True)                 # use_cache defaults True
-    exact = engine.merge(contribs, "task_arithmetic", base=base, lam=0.7)
-    legacy = reference_apply("task_arithmetic", contribs, base=base,
-                            lam=0.7)
-    assert _bytes_equal(exact, legacy)
-    clear_cache()
+def test_engine_ties_histogram_trim_exact_and_cached():
+    """TIES with the histogram trim runs the engine's one exact path:
+    bitwise the reference, and cached."""
+    contribs, base = _pytree_contribs(k=3, seed=19)
+    kw = dict(base=base, trim_method="histogram")
+    cache = engine.EngineCache()
+    got = engine.merge(contribs, "ties", cache=cache, **kw)
+    assert _bytes_equal(got, reference_apply("ties", contribs, **kw))
+    dispatches = engine.exec_stats(cache)["dispatches"]
+    again = engine.merge(contribs, "ties", cache=cache, **kw)
+    assert engine.exec_stats(cache)["hits"] == \
+        len(jax.tree_util.tree_leaves(base))
+    assert engine.exec_stats(cache)["dispatches"] == dispatches
+    assert _bytes_equal(again, got)
+
+
+def test_engine_quantized_contributions_match_dequantized():
+    """int8 contributions (`CompressedTree`) merge bitwise equal to
+    their `decompress_tree` copies: each slice is densified where it is
+    read (`dequant_leaves` counts them), and the output is cached."""
+    contribs, base = _pytree_contribs(k=3, seed=23)
+    cts = [compress_tree(c) for c in contribs]
+    cache = engine.EngineCache()
+    got = engine.merge(cts, "weight_average", cache=cache)
+    n_leaves = len(jax.tree_util.tree_leaves(base))
+    assert engine.exec_stats(cache)["dequant_leaves"] == 3 * n_leaves
+    want = engine.merge([decompress_tree(c) for c in cts],
+                        "weight_average", cache=engine.EngineCache())
+    assert _bytes_equal(got, want)
+    again = engine.merge(cts, "weight_average", cache=cache)
+    assert engine.exec_stats(cache)["hits"] == n_leaves
+    assert engine.exec_stats(cache)["dequant_leaves"] == 3 * n_leaves
+    assert _bytes_equal(again, got)
 
 
 def test_syncnode_resolve_counts_blob_pulls():

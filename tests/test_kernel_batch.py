@@ -1,7 +1,6 @@
-"""Kernel-frontier flat-batch dispatch: byte-identity property grids
+"""Kernel library flat-batch dispatch: byte-identity property grids
 for histogram-trim TIES, counter-RNG DARE, and int8 merge-on-arrival,
-plus the engine routes, KernelEnv plumbing, and note_meta scale
-threading.
+plus KernelEnv plumbing and note_meta scale threading.
 
 Byte-identity contract (DESIGN.md §6): kernel outputs are compared
 against the jit-compiled eager reference for arithmetic done inside the
@@ -17,7 +16,6 @@ import pytest
 
 from repro.core import engine
 from repro.core.compression import compress_tree, decompress_tree
-from repro.core.resolve import clear_cache
 from repro.kernels import ops, ref
 from repro.kernels.common import pad_flat, pad_stacked, pad_stacked_raw
 from repro.kernels.config import kernel_env
@@ -184,14 +182,10 @@ def test_kernel_env_env_overrides(monkeypatch):
     monkeypatch.setenv("REPRO_KERNEL_INTERPRET", "1")
     monkeypatch.setenv("REPRO_KERNEL_BLOCK", "512")
     monkeypatch.setenv("REPRO_KERNEL_HIST_BINS", "128")
-    monkeypatch.setenv("REPRO_KERNEL_QUANTIZED", "0")
-    monkeypatch.setenv("REPRO_KERNEL_DARE_RNG", "1")
     kernel_env.reset()
     assert kernel_env.resolve_interpret() is True
     assert kernel_env.block == 512
     assert kernel_env.hist_bins == 128
-    assert kernel_env.quantized is False
-    assert kernel_env.dare_kernel_rng is True
 
 
 def test_kernel_env_rejects_bad_values(monkeypatch):
@@ -222,7 +216,7 @@ def test_kernel_env_drives_ops_defaults(monkeypatch):
     assert np.array_equal(np.asarray(out), np.asarray(explicit))
 
 
-# ------------------------------------------------------- engine routes ---
+# ------------------------------------------------- meta scale threading ---
 
 
 def _tree_contribs(k=3, seed=11):
@@ -230,153 +224,6 @@ def _tree_contribs(k=3, seed=11):
     return [{"a": jnp.asarray(rng.standard_normal((8, 33)), jnp.float32),
              "b": jnp.asarray(rng.standard_normal(257), jnp.float32)}
             for _ in range(k)]
-
-
-def test_engine_ties_hist_route_matches_exact_path():
-    """ties + trim_method=histogram batches through the 3-launch kernel
-    pipeline (dispatch counter proves it) and agrees with the unfused
-    exact execution to fp32 tolerance."""
-    contribs = _tree_contribs()
-    base = jax.tree_util.tree_map(jnp.zeros_like, contribs[0])
-    cache = engine.EngineCache()
-    plan = engine.plan_merge([engine.contrib_meta(c) for c in contribs],
-                             "ties", base=base, trim_method="histogram")
-    got = engine.execute_plan(plan, contribs, base=base, use_cache=False,
-                              pallas=True, max_batch_bytes=1 << 20,
-                              cache=cache)
-    assert cache.obs.counter("kernel_dispatch_total").value(
-        kernel="ties_hist") >= 1
-    want = engine.execute_plan(plan, contribs, base=base,
-                               use_cache=False, cache=engine.EngineCache())
-    for g, w in zip(jax.tree_util.tree_leaves(got),
-                    jax.tree_util.tree_leaves(want)):
-        np.testing.assert_allclose(np.asarray(g), np.asarray(w),
-                                   rtol=1e-5, atol=1e-6)
-
-
-def test_engine_quant_route_zero_dequant():
-    """Quantized contributions merge through the int8 kernel without
-    EVER densifying a leaf: dequant_leaves stays 0 and the
-    engine_quant_leaves_merged_total counter covers every task."""
-    contribs = _tree_contribs(seed=12)
-    cts = [compress_tree(c) for c in contribs]
-    cache = engine.EngineCache()
-    plan = engine.plan_merge([engine.contrib_meta(c) for c in cts],
-                             "weight_average")
-    got = engine.execute_plan(plan, cts, use_cache=False, pallas=True,
-                              max_batch_bytes=1 << 20, cache=cache)
-    assert cache.stats["dequant_leaves"] == 0
-    assert cache.obs.counter("engine_quant_leaves_merged_total").value() == 2
-    assert cache.obs.counter("kernel_dispatch_total").value(
-        kernel="quant_nary") >= 1
-    # agrees with dequantize-then-merge on the dense trees
-    dense = [decompress_tree(c) for c in cts]
-    want = engine.execute_plan(
-        engine.plan_merge([engine.contrib_meta(c) for c in dense],
-                          "weight_average"),
-        dense, use_cache=False, cache=engine.EngineCache())
-    for g, w in zip(jax.tree_util.tree_leaves(got),
-                    jax.tree_util.tree_leaves(want)):
-        np.testing.assert_allclose(np.asarray(g), np.asarray(w),
-                                   rtol=1e-5, atol=1e-6)
-
-
-def test_engine_quant_route_respects_toggle():
-    """REPRO_KERNEL_QUANTIZED=0 falls back to dequantize-then-merge
-    (dequant counter fires, quant kernel does not)."""
-    kernel_env.quantized = False
-    cts = [compress_tree(c) for c in _tree_contribs(seed=13)]
-    cache = engine.EngineCache()
-    plan = engine.plan_merge([engine.contrib_meta(c) for c in cts],
-                             "weight_average")
-    engine.execute_plan(plan, cts, use_cache=False, pallas=True,
-                        max_batch_bytes=1 << 20, cache=cache)
-    assert cache.stats["dequant_leaves"] > 0
-    assert cache.obs.counter("kernel_dispatch_total").value(
-        kernel="quant_nary") == 0
-
-
-def test_engine_dare_route_opt_in():
-    """The DARE kernel route is off by default (its counter RNG is a
-    different sampler than the catalog's `jax.random`); opting in via
-    kernel_env routes the batch through it, deterministically, and
-    byte-identically to the ops-level flat batch with the plan's
-    per-task seeds."""
-    contribs = _tree_contribs(seed=14)
-    base = jax.tree_util.tree_map(jnp.zeros_like, contribs[0])
-    metas = [engine.contrib_meta(c) for c in contribs]
-    plan = engine.plan_merge(metas, "dare", base=base, seed=5)
-    cache = engine.EngineCache()
-    engine.execute_plan(plan, contribs, base=base, use_cache=False,
-                        pallas=True, max_batch_bytes=1 << 20, cache=cache)
-    assert cache.obs.counter("kernel_dispatch_total").value(
-        kernel="dare") == 0                      # default: off
-    kernel_env.dare_kernel_rng = True
-    cache2 = engine.EngineCache()
-    got = engine.execute_plan(plan, contribs, base=base, use_cache=False,
-                              pallas=True, max_batch_bytes=1 << 20,
-                              cache=cache2)
-    assert cache2.obs.counter("kernel_dispatch_total").value(
-        kernel="dare") >= 1
-    again = engine.execute_plan(plan, contribs, base=base,
-                                use_cache=False, pallas=True,
-                                max_batch_bytes=1 << 20,
-                                cache=engine.EngineCache())
-    for g, a in zip(jax.tree_util.tree_leaves(got),
-                    jax.tree_util.tree_leaves(again)):
-        assert np.asarray(g).tobytes() == np.asarray(a).tobytes()
-    # ops-level oracle: seed = plan.seed + task.index, leaf order by task
-    leaves0 = jax.tree_util.tree_leaves(contribs[0])
-    stacked = [jnp.stack([jax.tree_util.tree_leaves(c)[t.index]
-                          .reshape(-1) for c in contribs])
-               for t in plan.tasks]
-    bases = [jnp.zeros(s.shape[1], jnp.float32) for s in stacked]
-    want = ops.dare_batch_merge(
-        stacked, bases, [plan.seed + t.index for t in plan.tasks], 0.5)
-    got_leaves = jax.tree_util.tree_leaves(got)
-    for t, w in zip(plan.tasks, want):
-        g = got_leaves[t.index]
-        assert np.asarray(g).reshape(-1).tobytes() == \
-            np.asarray(w).tobytes()
-    assert len(leaves0) == len(plan.tasks)
-
-
-def test_kernel_routes_never_poison_exact_cache():
-    """A pallas=True histogram-TIES merge with caching enabled must not
-    leave approximate leaves for a later exact merge to return."""
-    clear_cache()
-    contribs = _tree_contribs(seed=15)
-    base = jax.tree_util.tree_map(jnp.zeros_like, contribs[0])
-    kw = dict(base=base, trim_method="histogram")
-    engine.merge(contribs, "ties", pallas=True,
-                 max_batch_bytes=1 << 20, **kw)   # use_cache defaults True
-    exact = engine.merge(contribs, "ties", **kw)
-    clear_cache()
-    legacy = engine.merge(contribs, "ties", **kw)
-    for a, b in zip(jax.tree_util.tree_leaves(exact),
-                    jax.tree_util.tree_leaves(legacy)):
-        assert np.asarray(a).tobytes() == np.asarray(b).tobytes()
-    clear_cache()
-
-
-def test_engine_integer_leaves_take_eager_path():
-    """Integer-dtype leaves never enter the fp32 kernel routes (the
-    _unpad truncation guard would otherwise be reachable)."""
-    rng = np.random.default_rng(16)
-    contribs = [{"ids": jnp.asarray(rng.integers(0, 9, 64), jnp.int32),
-                 "w": jnp.asarray(rng.standard_normal(64), jnp.float32)}
-                for _ in range(3)]
-    got = engine.merge(contribs, "weight_average", use_cache=False,
-                       pallas=True, max_batch_bytes=1 << 20)
-    want = engine.merge(contribs, "weight_average", use_cache=False)
-    for g, w in zip(jax.tree_util.tree_leaves(got),
-                    jax.tree_util.tree_leaves(want)):
-        np.testing.assert_allclose(np.asarray(g, np.float32),
-                                   np.asarray(w, np.float32),
-                                   rtol=1e-5, atol=1e-5)
-
-
-# ------------------------------------------------- meta scale threading ---
 
 
 def test_contrib_meta_quantized_digests_match_dense():
@@ -391,13 +238,12 @@ def test_contrib_meta_quantized_digests_match_dense():
     assert mq.scales is not None and all(
         s is not None for s in mq.scales)
     assert md.scales is None
-    assert mq.scale_of(0) == mq.scales[0]
-    assert md.scale_of(0) is None
 
 
 def test_note_meta_threads_scales_into_plan():
-    """note_meta(scales=) lands on the LeafTask: the planner prices
-    int8 wire payloads at 1 byte/element and marks the task quantized."""
+    """note_meta(scales=) keeps the announced scales on the meta, and
+    the planner prices the leaf's stack at its dense width: the
+    executor densifies every int8 slice before it stacks it."""
     tree = {"a": jnp.asarray(np.random.default_rng(18)
                              .standard_normal(300), jnp.float32)}
     ct = compress_tree(tree)
@@ -409,7 +255,5 @@ def test_note_meta_threads_scales_into_plan():
     assert m2.scales == m.scales
     plan = engine.plan_merge([m, m2], "weight_average")
     (task,) = plan.tasks
-    assert task.quantized
-    assert task.scales == (m.scales[0], m.scales[0])
-    # int8 pricing: k * numel * 1 byte, not * 4
-    assert task.stacked_nbytes == 2 * 300
+    # dense pricing: k * numel * 4 bytes (float32), not the int8 width
+    assert task.stacked_nbytes == 2 * 300 * 4

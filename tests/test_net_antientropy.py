@@ -187,8 +187,8 @@ def test_compressed_blob_sync_deterministic():
 
 def test_keep_quantized_stores_int8_payloads():
     """keep_quantized=True stores arriving CompressedTree payloads
-    as-is (merge-on-arrival feedstock for the engine's int8 kernel
-    route) instead of densifying; content identity is unchanged because
+    as-is (the engine densifies each int8 slice where it reads it)
+    instead of densifying on arrival; content identity is unchanged because
     digests are defined on dequantized values."""
     from repro.core.compression import CompressedTree, decompress_tree
     rng = np.random.default_rng(21)

@@ -10,11 +10,14 @@ from typing import Dict, Iterator, List, Optional, Set
 
 from tools.detcheck.core import FileContext, ProjectContext, rule, Violation
 
-# Producers whose outputs are fp32-accumulated *tolerance* results (the
-# kernel frontier's flat-batch routes). The last element of their
-# returned tuple is the exactness flag; anything they produce must not
-# reach the byte-exact engine cache unless that flag gates the write.
-KERNEL_PRODUCERS = {"_execute_batch", "_kernel_batch"}
+# Producers whose outputs are fp32-accumulated *tolerance* results: a
+# kernel route's flat-batch dispatch, `_kernel_batch`, should one be
+# brought back into the engine. The engine's own batch path
+# (`_execute_batch`) is exact and is not one of them. The last element
+# of a producer's returned tuple is the exactness flag; anything it
+# produces must not reach the byte-exact engine cache unless that flag
+# gates the write.
+KERNEL_PRODUCERS = {"_kernel_batch"}
 EXACTNESS_GUARD_HINTS = ("approximate", "exact")
 
 
@@ -23,7 +26,7 @@ EXACTNESS_GUARD_HINTS = ("approximate", "exact")
                 "byte-exact; writing one into the exact-path engine "
                 "cache poisons every later warm hit with bytes that "
                 "differ from the reference semantics.",
-      example="out, auxs, approx = _execute_batch(...); "
+      example="out, auxs, approx = _kernel_batch(...); "
               "cache.put(t.sub_root, out[0], nb)")
 def hyg001(ctx: FileContext) -> Iterator[Violation]:
     for fn in ast.walk(ctx.tree):
