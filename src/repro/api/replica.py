@@ -35,9 +35,12 @@ from __future__ import annotations
 
 from typing import Any, Dict, Iterable, Optional, Tuple
 
+import jax
+
 from repro.api.spec import MergeSpec
 from repro.core.engine import CacheInfo, EngineCache, seed_meta
-from repro.core.hashing import pytree_leaf_digests, tree_nbytes
+from repro.core.hashing import (digest_workers, pytree_leaf_digests,
+                                tree_nbytes)
 from repro.core.state import CRDTMergeState
 from repro.core.trust import TrustState
 from repro.obs import MetricsRegistry, span
@@ -135,13 +138,21 @@ class Replica:
                     site: str) -> Tuple[str, Dict[str, bytes]]:
         """Content id of `tree` (hex SHA-256) and the digests of its
         leaves by path, spanned as `layer1.digest` and counted under
-        `hash_bytes_total{site}` on this replica's registry."""
+        `hash_bytes_total{site}` on this replica's registry, and under
+        `hash_pooled_leaves_total{site}` where the leaves were hashed
+        on the digest pool."""
         nbytes = tree_nbytes(tree)
-        with span("layer1.digest", bytes=nbytes) as sp:
+        n_leaves = len(jax.tree_util.tree_leaves(tree))
+        workers = digest_workers(n_leaves)
+        with span("layer1.digest", bytes=nbytes, leaves=n_leaves,
+                  workers=workers) as sp:
             digest, leaf_digests = pytree_leaf_digests(tree)
             ref = digest.hex()
             sp.set(eid=ref[:16])
         self.obs.counter("hash_bytes_total").inc(nbytes, site=site)
+        if workers > 1:
+            self.obs.counter("hash_pooled_leaves_total").inc(n_leaves,
+                                                             site=site)
         return ref, leaf_digests
 
     def add(self, contribution: Any, *,

@@ -17,12 +17,20 @@ Two tiers:
 digest of a pytree hashes, for its caller's `hash_bytes_total`.
 `pytree_leaf_digests` returns the per-leaf digests a content id was
 combined from, so the planner can take them instead of hashing the
-same leaves again.
+same leaves again. It hashes the same bytes as `tensor_digest`, but
+not one leaf after another: every leaf's copy to the host starts at
+once, and the leaves are hashed straight from their host buffers on
+`digest_workers` threads of a shared pool (`hashlib` releases the GIL
+while it hashes). Those threads open no spans: the tracer keeps one
+stack, so the caller's span covers the whole digest.
 """
 from __future__ import annotations
 
 import hashlib
-from typing import Dict, Tuple
+import os
+import threading
+from concurrent.futures import ThreadPoolExecutor, wait
+from typing import Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -49,6 +57,68 @@ def tensor_digest(arr) -> bytes:
         return h.digest()
 
 
+def _leaf_digest(leaf) -> bytes:
+    """`tensor_digest` of `leaf`, unspanned, hashed from the host
+    array's own buffer: a `uint8` view of it holds exactly the bytes
+    that `tobytes()` would copy. An array of references (an object
+    leaf) has no such view and is copied, as `tensor_digest` does."""
+    a = np.asarray(leaf)
+    h = hashlib.sha256()
+    h.update(str(a.dtype).encode())
+    h.update(b"|")
+    h.update(str(a.shape).encode())
+    h.update(b"|")
+    c = np.ascontiguousarray(a)
+    h.update(c.tobytes() if c.dtype.hasobject
+             else c.reshape(-1).view(np.uint8))
+    return h.digest()
+
+
+def digest_workers(n_leaves: int) -> int:
+    """Threads `pytree_leaf_digests` hashes `n_leaves` leaves on: one a
+    leaf, up to the host's cores. 1 means inline on the caller."""
+    return max(1, min(n_leaves, os.cpu_count() or 1))
+
+
+_POOL: Optional[ThreadPoolExecutor] = None
+_POOL_LOCK = threading.Lock()
+
+
+def _pool() -> ThreadPoolExecutor:
+    global _POOL
+    with _POOL_LOCK:
+        if _POOL is None:
+            _POOL = ThreadPoolExecutor(max_workers=os.cpu_count() or 1,
+                                       thread_name_prefix="leaf-digest")
+        return _POOL
+
+
+def _digest_leaves(leaves: List) -> List[bytes]:
+    """`_leaf_digest` of each leaf, in order. Every device-to-host copy
+    starts first, largest leaf first; the leaves are then hashed on the
+    pool, largest first, so that the last copy to land is what the
+    caller waits for. A leaf whose digest raises re-raises here, once
+    no task of this call is still running."""
+    largest_first = sorted(range(len(leaves)),
+                           key=lambda i: -int(getattr(leaves[i], "nbytes",
+                                                      0)))
+    for i in largest_first:
+        start_copy = getattr(leaves[i], "copy_to_host_async", None)
+        if start_copy is not None:
+            start_copy()
+    if digest_workers(len(leaves)) == 1:
+        return [_leaf_digest(leaf) for leaf in leaves]
+    pool = _pool()
+    futures = {i: pool.submit(_leaf_digest, leaves[i])
+               for i in largest_first}
+    try:
+        return [futures[i].result() for i in range(len(leaves))]
+    finally:
+        for f in futures.values():
+            f.cancel()
+        wait(futures.values())
+
+
 def tree_nbytes(tree) -> int:
     """Bytes of a pytree's leaves: what `pytree_digest` hashes (read
     from the arrays' metadata; nothing is copied to the host)."""
@@ -58,16 +128,17 @@ def tree_nbytes(tree) -> int:
 
 def pytree_leaf_digests(tree) -> Tuple[bytes, Dict[str, bytes]]:
     """SHA-256 of a parameter pytree together with the `tensor_digest`
-    of each leaf, keyed by its `keystr` path: leaves hashed once,
-    combined in sorted path order. A path two leaves share keeps the
-    last one's digest only, so a caller reusing the digests checks
-    that the map holds one entry per leaf."""
+    of each leaf, keyed by its `keystr` path: leaves hashed once, on
+    `digest_workers` threads, combined in sorted path order. A path
+    two leaves share keeps the last one's digest only, so a caller
+    reusing the digests checks that the map holds one entry per leaf."""
     flat, _ = jax.tree_util.tree_flatten_with_path(tree)
+    items = sorted(((jax.tree_util.keystr(p), l) for p, l in flat),
+                   key=lambda kv: kv[0])
+    digests = _digest_leaves([leaf for _, leaf in items])
     h = hashlib.sha256()
     leaf_digests: Dict[str, bytes] = {}
-    for path, leaf in sorted(((jax.tree_util.keystr(p), l) for p, l in flat),
-                             key=lambda kv: kv[0]):
-        digest = tensor_digest(leaf)
+    for (path, _), digest in zip(items, digests):
         h.update(path.encode())
         h.update(digest)
         leaf_digests[path] = digest

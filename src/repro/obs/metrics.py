@@ -138,6 +138,11 @@ declare("hash_bytes_total", "counter",
         "contribute, the planner's leaf digests in contrib_meta, the "
         "base-leaf digests of a plan, the base ref in register_base",
         labels=("site",))
+declare("hash_pooled_leaves_total", "counter",
+        "Leaves of a content id hashed on the digest pool's threads, by "
+        "call site (contribute, register_base); a one-leaf tree or a "
+        "one-core host hashes inline and counts nothing",
+        labels=("site",))
 declare("contrib_meta_total", "counter",
         "Planner metas of contributions built, by source: content_id "
         "(from the leaf digests of the content id contribute computed), "

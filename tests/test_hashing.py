@@ -1,11 +1,17 @@
 """Content hashing + sharding-invariant fingerprints."""
+import hashlib
+import sys
+import threading
+
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 from _hypothesis_compat import given, settings, st
 
 from repro.core.hashing import (fingerprint2x32,
     pytree_digest,
+    pytree_leaf_digests,
     tensor_digest,
     tree_fingerprint)
 
@@ -65,3 +71,148 @@ def test_tree_fingerprint_structure_sensitive():
     f1 = tree_fingerprint({"x": a, "y": b})
     f2 = tree_fingerprint({"x": b, "y": a})
     assert not bool(jnp.array_equal(f1, f2))
+
+
+# ---------------------------------------------------------------------------
+# The pooled pytree digest hashes exactly the serial composition's bytes
+# ---------------------------------------------------------------------------
+
+
+def _serial(tree):
+    """The content digest as one `tensor_digest` per leaf, combined in
+    sorted `keystr` order, and the leaf digests it was made of."""
+    flat, _ = jax.tree_util.tree_flatten_with_path(tree)
+    leaves = {jax.tree_util.keystr(p): tensor_digest(l) for p, l in flat}
+    h = hashlib.sha256()
+    for path in sorted(leaves):
+        h.update(path.encode())
+        h.update(leaves[path])
+    return h.digest(), leaves
+
+
+def _mixed(xp):
+    rng = np.random.default_rng(3)
+    return {"w": xp.asarray(rng.standard_normal((64, 48)), jnp.bfloat16),
+            "norm": xp.asarray(rng.standard_normal(48), np.float32),
+            "q": xp.asarray(rng.integers(-128, 128, (5, 7)), np.int8),
+            "mask": xp.asarray(rng.random(9) > 0.5),
+            "scalar": xp.asarray(2.5, np.float32),
+            "empty": xp.zeros((0, 3), np.float32)}
+
+
+_OBJECT = object()
+
+TREES = {
+    "jax_mixed": lambda: _mixed(jnp),
+    "numpy_mixed": lambda: _mixed(np),
+    "transposed_view": lambda: {
+        "t": np.arange(24, dtype=np.float32).reshape(4, 6).T,
+        "u": jnp.ones((3, 5), jnp.bfloat16)},
+    # list indices sort as text: "[10]" < "[2]", unlike the flatten order
+    "sorted_order_differs": lambda: {
+        "layers": [{"w": jnp.full((4, i + 1), i, jnp.float32)}
+                   for i in range(12)],
+        "a!": np.arange(3, dtype=np.int8), "a": np.ones(2, np.int8)},
+    "one_leaf": lambda: jnp.arange(10, dtype=jnp.bfloat16),
+    # an unregistered object is a leaf: an array of one reference
+    "object_leaf": lambda: {"o": _OBJECT, "w": jnp.ones(3, jnp.float32)},
+}
+
+
+@pytest.mark.parametrize("cores", [1, 4])
+@pytest.mark.parametrize("name", sorted(TREES))
+def test_pooled_digests_equal_serial_composition(name, cores, monkeypatch):
+    monkeypatch.setattr("os.cpu_count", lambda: cores)
+    tree = TREES[name]()
+    digest, leaves = pytree_leaf_digests(tree)
+    want_digest, want_leaves = _serial(tree)
+    assert leaves == want_leaves
+    assert digest == want_digest == pytree_digest(tree)
+
+
+def test_content_id_pinned():
+    tree = {"w": jnp.arange(6, dtype=jnp.bfloat16).reshape(2, 3),
+            "layers": [np.arange(i, i + 3, dtype=np.int8)
+                       for i in range(11)],
+            "mask": np.array([True, False]),
+            "scale": np.float32(1.5),
+            "empty": np.zeros((0, 4), np.float32)}
+    assert pytree_digest(tree).hex() == (
+        "475c7919d95666d0e4a0256d0505248bfa2adf6727003db7787f5025f2b019d0")
+
+
+class _Leaf:
+    """A leaf that logs its copy start and its fetch (with the thread
+    that fetched it), and can fail on fetch."""
+
+    def __init__(self, log, name, n, fail=False):
+        self.log, self.name, self.fail = log, name, fail
+        self.value = np.full(n, n, np.float32)
+        self.nbytes = self.value.nbytes
+
+    def copy_to_host_async(self):
+        self.log.append(("copy", self.name, None))
+
+    def __array__(self, dtype=None, copy=None):
+        self.log.append(("fetch", self.name,
+                         threading.current_thread() is
+                         threading.main_thread()))
+        if self.fail:
+            raise RuntimeError(f"leaf {self.name} failed")
+        return self.value
+
+
+@pytest.mark.parametrize("cores", [1, 4])
+def test_copies_start_largest_first_before_any_fetch(cores, monkeypatch):
+    monkeypatch.setattr("os.cpu_count", lambda: cores)
+    log = []
+    sizes = {"a": 100, "b": 1000, "c": 10, "d": 500}
+    tree = {k: _Leaf(log, k, n) for k, n in sizes.items()}
+    digest, _ = pytree_leaf_digests(tree)
+    assert digest == pytree_digest({k: l.value for k, l in tree.items()})
+    copies = [e for e in log if e[0] == "copy"]
+    fetches = [e for e in log if e[0] == "fetch"]
+    assert log[:len(sizes)] == copies
+    assert [e[1] for e in copies] == ["b", "d", "a", "c"]
+    assert sorted(e[1] for e in fetches) == sorted(sizes)
+    on_main = {e[2] for e in fetches}
+    assert on_main == ({True} if cores == 1 else {False})
+
+
+@pytest.mark.parametrize("cores", [1, 4])
+def test_failing_leaf_raises_on_caller(cores, monkeypatch):
+    monkeypatch.setattr("os.cpu_count", lambda: cores)
+    log = []
+    tree = {"a": _Leaf(log, "a", 64), "b": _Leaf(log, "b", 8, fail=True),
+            "c": _Leaf(log, "c", 32)}
+    with pytest.raises(RuntimeError, match="leaf b failed"):
+        pytree_leaf_digests(tree)
+
+
+def test_concurrent_callers_share_the_pool(monkeypatch):
+    """Threads that digest at once, more of them than the pool has
+    workers, each get their own tree's digests."""
+    monkeypatch.setattr("os.cpu_count", lambda: 2)
+    trees = [{"w": np.full((64, 8 + i), i, np.float32),
+              "b": np.arange(i + 1, dtype=np.int8),
+              "n": {"x": np.ones(i + 3, np.float32)}} for i in range(12)]
+    want = [_serial(t) for t in trees]
+    got = [None] * len(trees)
+
+    def work(i):
+        for _ in range(5):
+            got[i] = pytree_leaf_digests(trees[i])
+
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        threads = [threading.Thread(target=work, args=(i,))
+                   for i in range(len(trees))]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=60)
+    finally:
+        sys.setswitchinterval(interval)
+    assert not any(t.is_alive() for t in threads)
+    assert got == want
